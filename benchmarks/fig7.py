@@ -90,6 +90,7 @@ import numpy as np
 
 from repro.configs import bcnn_cifar10 as pc
 from repro.core import bcnn
+from repro.launch import compile_cache
 from repro.serve import BCNNEngine, drive_poisson
 
 
@@ -1058,6 +1059,7 @@ if __name__ == "__main__":
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also dump the result dict as JSON")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.pipeline:
         out = run_pipeline(n_slots=args.slots)
     elif args.offline:

@@ -430,6 +430,11 @@ class PackedForward:
     def __call__(self, x01: jnp.ndarray) -> jnp.ndarray:
         return self._jit(self._arrays, x01)
 
+    def lower(self, x01: jnp.ndarray):
+        """The step lowered for ``x01``'s shape, without running it —
+        ``.compile().as_text()`` is the HLO the device executes."""
+        return self._jit.lower(self._arrays, x01)
+
     def swap(self, new_packed: BCNNPacked) -> None:
         """Replace the served weights; zero recompiles (shapes must match,
         checked by ``assert_swap_compatible``)."""

@@ -19,9 +19,10 @@ Candidate space (per layer / group, legality shared with the heuristics):
 * conv ``strategy`` per binary conv layer — "direct" where
   `core/bconv.py::resolve_strategy` would allow it (per-position layout
   present, 32-aligned channels), "im2col" always;
-* fused-pair (th, tw) output tiles — every power-of-two tile whose halo
+* fused-pair (th, tw) output tiles — every power-of-two height whose halo
   scratch fits the `kernels/xnor_conv_fused.py::halo_scratch` VMEM budget
-  (the exact legality rule ``pick_tiles`` uses);
+  (the exact legality rule ``pick_tiles`` uses), at the one width Mosaic
+  accepts;
 * cross-layer fusion on/off — the fused pair raced against its two-layer
   sequential fold;
 * LM decode GEMM ``mode`` ("bw" | "xnor") via ``autotune_lm_mode``.
@@ -46,6 +47,7 @@ import jax.numpy as jnp
 
 from repro.core import bcnn, bconv, execution_plan
 from repro.kernels import xnor_conv_fused as kfused
+from repro.kernels.xnor_conv import tile_width
 
 AUTOTUNE_REPS = 3          # timed calls per candidate (median wins)
 AUTOTUNE_WARMUP = 1        # untimed warmup calls (compile + caches)
@@ -76,25 +78,21 @@ def tile_candidates(ho: int, wo: int, *, pf: int, fhb: int, fwb: int,
                     oa: int, la: int,
                     budget: int = kfused.SCRATCH_BUDGET
                     ) -> tuple[tuple[int, int], ...]:
-    """Every legal (th, tw) fused-pair output tile: powers of two up to the
-    default block for the extent, whose
-    `kernels/xnor_conv_fused.py::halo_scratch` fits ``budget``. The
-    ``pick_tiles`` heuristic choice is always a member."""
+    """Every legal (th, tw) fused-pair output tile: power-of-two heights up
+    to the default block for the extent whose
+    `kernels/xnor_conv_fused.py::halo_scratch` fits ``budget``, at the one
+    width the chip's compiler accepts
+    (`kernels/xnor_conv.py::tile_width`). The ``pick_tiles`` heuristic
+    choice is always a member when it fits the budget."""
     from repro.kernels.ops import _block_for
-
-    def _po2_up_to(m: int) -> list[int]:
-        out, t = [], 1
-        while t <= m:
-            out.append(t)
-            t *= 2
-        return out
-
+    tw = tile_width(wo)
     cands = []
-    for th in _po2_up_to(_block_for(ho, kfused.TH, floor=1)):
-        for tw in _po2_up_to(_block_for(wo, kfused.TW, floor=1)):
-            if kfused.halo_scratch(th, tw, pf=pf, fhb=fhb, fwb=fwb,
-                                   oa=oa, la=la) <= budget:
-                cands.append((th, tw))
+    th = 1
+    while th <= _block_for(ho, kfused.TH, floor=1):
+        if kfused.halo_scratch(th, tw, pf=pf, fhb=fhb, fwb=fwb, oa=oa,
+                               la=la) <= budget:
+            cands.append((th, tw))
+        th *= 2
     return tuple(cands)
 
 

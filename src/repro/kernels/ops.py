@@ -98,14 +98,9 @@ def xnor_matmul(a_words: jnp.ndarray, w_words: jnp.ndarray, *, k: int,
         return y.reshape(*lead, n)
 
     bm = _block_for(a2.shape[0], kern.BM)
-    bn = _block_for(n, kern.BN)
+    bn = kern.BN                        # lane blocks: always 128 wide
     a2, m_true = _pad_rows(a2, bm)
     w_p, n_true = _pad_rows(w_words, bn)
-    # pad K-words up to the vpu inner step
-    rem_kw = (-kw) % kern.BKW
-    if rem_kw:
-        a2 = jnp.pad(a2, ((0, 0), (0, rem_kw)))
-        w_p = jnp.pad(w_p, ((0, 0), (0, rem_kw)))
     c = f = None
     if thr_c is not None:
         c = jnp.pad(thr_c.astype(jnp.float32), (0, w_p.shape[0] - n_true),
@@ -167,8 +162,8 @@ def xnor_conv2d(a_bits: jnp.ndarray, w_words: jnp.ndarray, *, k: int,
     # tile the output grid; pad the packed image so every tile's reception
     # span exists (extra rows/cols are zero words = −1 bits, sliced away)
     th = _block_for(ho, kconv.TH, floor=1)
-    tw = _block_for(wo, kconv.TW, floor=1)
-    bo = _block_for(o, kconv.BO)
+    tw = kconv.tile_width(wo)
+    bo = kconv.BO                       # lane blocks: always 128 wide
     ho_p = -(-ho // th) * th
     wo_p = -(-wo // tw) * tw
     hp_need = (ho_p - 1) * stride + fh
@@ -318,13 +313,17 @@ def binary_weight_matmul(a: jnp.ndarray, w_words: jnp.ndarray, *, k: int,
     if kk < kw * bitpack.PACK:
         a2 = jnp.pad(a2, ((0, 0), (0, kw * bitpack.PACK - kk)))
     bm = _block_for(a2.shape[0], kern.BM)
-    bn = _block_for(n, kern.BN)
-    bkw = kw if kw <= 32 else 32
+    bn = kern.BN                        # lane blocks: always 128 wide
+    bkw = kw if kw <= 128 else 128      # 128-aligned in-kernel K chunks
     rem_kw = (-kw) % bkw
     w_p = w_words
     if rem_kw:
         w_p = jnp.pad(w_p, ((0, 0), (0, rem_kw)))
         a2 = jnp.pad(a2, ((0, 0), (0, rem_kw * bitpack.PACK)))
+    # the kernel unpacks each bkw-word weight chunk in bit-plane order
+    # (`kernels/xnor_matmul.py::_unpack_pm1`): lay the activations out alike
+    a2 = a2.reshape(a2.shape[0], -1, bkw, bitpack.PACK).swapaxes(2, 3)
+    a2 = a2.reshape(a2.shape[0], -1)
     a2, m_true = _pad_rows(a2, bm)
     w_p, n_true = _pad_rows(w_p, bn)
     s = None

@@ -39,13 +39,20 @@ from jax.experimental import pallas as pl
 
 from repro.core import bitpack
 from repro.core.bitpack import PACK
-from repro.kernels.xnor_matmul import _unpack_pm1
+from repro.kernels.xnor_matmul import _agree_counts, _norm_binarize
 
 # Default output tile sizes: 8×8 spatial pixels × 128 output channels gives a
 # (64, 128) output tile — sublane/lane aligned on TPU.
 TH = 8     # output rows per block
 TW = 8     # output cols per block
 BO = 128   # output channels per block
+
+
+def tile_width(wo: int) -> int:
+    """Output-tile width: ``TW``, or the whole map when it is narrower.
+    Mosaic needs an output block's second-minor dimension to be a multiple
+    of 8 or the whole array, so the width is not a free tiling knob."""
+    return TW if wo >= TW else wo
 
 
 def pack_conv_weights(w: jnp.ndarray) -> jnp.ndarray:
@@ -85,59 +92,25 @@ def _gather_patches(a_ref, *, th: int, tw: int, fh: int, fw: int,
     return patches.reshape(th * tw, fh * fw * kwc)
 
 
-def _epilogue(y_l, c_ref, f_ref, out_ref, *, fuse_nb: bool):
-    """Shared NormBinarize epilogue: y_l (th·tw, bo) → out_ref (1, th, tw, bo)."""
-    th, tw, bo = out_ref.shape[1], out_ref.shape[2], out_ref.shape[3]
-    if fuse_nb:
-        ge = y_l >= c_ref[0][None, :]
-        bit = jnp.where(f_ref[0][None, :] != 0, ~ge, ge)
-        out_ref[...] = bit.astype(jnp.int32).reshape(1, th, tw, bo)
-    else:
-        out_ref[...] = y_l.reshape(1, th, tw, bo)
-
-
-def _xnor_conv_vpu_kernel(a_ref, w_ref, c_ref, f_ref, out_ref, *, fh: int,
-                          fw: int, stride: int, n_pad_bits: int,
-                          fuse_nb: bool):
-    """One (1, th, tw, bo) output tile; XNOR + popcount on the VPU.
+def _xnor_conv_kernel(a_ref, w_ref, c_ref, f_ref, out_ref, *, variant: str,
+                      fh: int, fw: int, stride: int, k: int, n_pad_bits: int,
+                      fuse_nb: bool):
+    """One (1, th, tw, bo) output tile.
 
     a_ref: (1, Hp, Wp, Cw) int32 packed image (full image resident in VMEM)
     w_ref: (bo, fh·fw·Cw) int32 per-position packed filters
     c_ref: (1, bo) float32 NormBinarize thresholds (if fuse_nb)
-    f_ref: (1, bo) int32 comparison-flip mask       (if fuse_nb)
+    f_ref: (1, bo) int32 {0,1} comparison-flip mask (if fuse_nb)
+    ``variant``: "vpu" XNOR + popcount or "mxu" unpack-dot
+    (`kernels/xnor_matmul.py::_agree_counts`).
     """
-    th, tw = out_ref.shape[1], out_ref.shape[2]
+    th, tw, bo = out_ref.shape[1], out_ref.shape[2], out_ref.shape[3]
     pm = _gather_patches(a_ref, th=th, tw=tw, fh=fh, fw=fw, stride=stride)
-    x = jnp.bitwise_xor(pm[:, None, :], w_ref[...][None, :, :])
-    agree = jax.lax.population_count(
-        jnp.bitwise_not(x).astype(jnp.uint32)).astype(jnp.int32)
-    y_l = agree.sum(axis=-1) - n_pad_bits           # (th·tw, bo)
+    y_l = _agree_counts(pm, w_ref[...], variant=variant, k=k,
+                        npad=n_pad_bits)                # (th·tw, bo)
     if fuse_nb:
-        yc = y_l.astype(jnp.float32)
-    else:
-        yc = y_l
-    _epilogue(yc, c_ref, f_ref, out_ref, fuse_nb=fuse_nb)
-
-
-def _xnor_conv_mxu_kernel(a_ref, w_ref, c_ref, f_ref, out_ref, *, fh: int,
-                          fw: int, stride: int, k: int, n_pad_bits: int,
-                          fuse_nb: bool):
-    """Same tile contract as the VPU kernel, compute on the MXU.
-
-    Pad bits agree ((−1)·(−1)) so dot_p = dot_true + n_pad;
-    y_l = (k + dot_p − n_pad) / 2 — identical to the matmul MXU kernel.
-    """
-    th, tw = out_ref.shape[1], out_ref.shape[2]
-    pm = _gather_patches(a_ref, th=th, tw=tw, fh=fh, fw=fw, stride=stride)
-    a_pm1 = _unpack_pm1(pm, jnp.bfloat16)           # (th·tw, L·32)
-    w_pm1 = _unpack_pm1(w_ref[...], jnp.bfloat16)   # (bo, L·32)
-    dot_p = jax.lax.dot_general(
-        a_pm1, w_pm1, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    y_l = (k + dot_p.astype(jnp.int32) - n_pad_bits) // 2
-    if fuse_nb:
-        y_l = y_l.astype(jnp.float32)
-    _epilogue(y_l, c_ref, f_ref, out_ref, fuse_nb=fuse_nb)
+        y_l = _norm_binarize(y_l, c_ref[...], f_ref[...])
+    out_ref[...] = y_l.reshape(1, th, tw, bo)
 
 
 def _conv_call(kernel, a_words, w_words, thr_c, thr_flip, *, ho: int, wo: int,
@@ -167,6 +140,16 @@ def _conv_call(kernel, a_words, w_words, thr_c, thr_flip, *, ho: int, wo: int,
     )(a_words, w_words, thr_c, thr_flip)
 
 
+def _conv_variant(variant, a_words, w_words, *, k, fh, fw, stride, ho, wo,
+                  thr_c, thr_flip, th, tw, bo, interpret):
+    kern = functools.partial(_xnor_conv_kernel, variant=variant, fh=fh,
+                             fw=fw, stride=stride, k=k,
+                             n_pad_bits=w_words.shape[1] * PACK - k,
+                             fuse_nb=thr_c is not None)
+    return _conv_call(kern, a_words, w_words, thr_c, thr_flip, ho=ho, wo=wo,
+                      th=th, tw=tw, bo=bo, interpret=interpret)
+
+
 def xnor_conv2d_vpu(a_words, w_words, *, k: int, fh: int, fw: int,
                     stride: int = 1, ho: int, wo: int, thr_c=None,
                     thr_flip=None, th: int = TH, tw: int = TW, bo: int = BO,
@@ -178,12 +161,10 @@ def xnor_conv2d_vpu(a_words, w_words, *, k: int, fh: int, fw: int,
     ``ho``/``wo`` are the padded output dims; the input must satisfy
     Hp ≥ (ho−1)·stride + fh (resp. W).
     """
-    n_pad_bits = w_words.shape[1] * PACK - k
-    kern = functools.partial(_xnor_conv_vpu_kernel, fh=fh, fw=fw,
-                             stride=stride, n_pad_bits=n_pad_bits,
-                             fuse_nb=thr_c is not None)
-    return _conv_call(kern, a_words, w_words, thr_c, thr_flip, ho=ho, wo=wo,
-                      th=th, tw=tw, bo=bo, interpret=interpret)
+    return _conv_variant("vpu", a_words, w_words, k=k, fh=fh, fw=fw,
+                         stride=stride, ho=ho, wo=wo, thr_c=thr_c,
+                         thr_flip=thr_flip, th=th, tw=tw, bo=bo,
+                         interpret=interpret)
 
 
 def xnor_conv2d_mxu(a_words, w_words, *, k: int, fh: int, fw: int,
@@ -192,9 +173,7 @@ def xnor_conv2d_mxu(a_words, w_words, *, k: int, fh: int, fw: int,
                     interpret: bool = False):
     """Direct packed conv via in-VMEM unpack + MXU dot. Bit-exact for
     k ≤ 2²⁴ (f32 accumulation of ±1 products is exact in that range)."""
-    n_pad_bits = w_words.shape[1] * PACK - k
-    kern = functools.partial(_xnor_conv_mxu_kernel, fh=fh, fw=fw,
-                             stride=stride, k=k, n_pad_bits=n_pad_bits,
-                             fuse_nb=thr_c is not None)
-    return _conv_call(kern, a_words, w_words, thr_c, thr_flip, ho=ho, wo=wo,
-                      th=th, tw=tw, bo=bo, interpret=interpret)
+    return _conv_variant("mxu", a_words, w_words, k=k, fh=fh, fw=fw,
+                         stride=stride, ho=ho, wo=wo, thr_c=thr_c,
+                         thr_flip=thr_flip, th=th, tw=tw, bo=bo,
+                         interpret=interpret)

@@ -41,18 +41,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.bitpack import PACK
-from repro.kernels.xnor_matmul import _unpack_pm1
+from repro.kernels.xnor_conv import tile_width
+from repro.kernels.xnor_matmul import (OCHUNK, SCRATCH_BUDGET, _agree_counts,
+                                       _norm_binarize)
 
-# Default spatial tile (B's pooled output pixels per program); shrunk by
-# pick_tiles when the halo scratch would outgrow the VMEM budget.
+# Default tile height (B's pooled output rows per program); shrunk by
+# pick_tiles when the halo scratch would outgrow the VMEM budget. The tile
+# width is `kernels/xnor_conv.py::tile_width` (8, or the whole map).
 TH = 8
-TW = 8
-# Output-channel chunk for the VPU popcount loops: bounds the (P, OCHUNK, L)
-# XNOR scratch while the filter words stay fully resident.
-OCHUNK = 128
-# VMEM scratch budget (int32 elements) for pick_tiles — conservative slice
-# of the ~16 MB/core VMEM, leaving room for weights + the bit map itself.
-SCRATCH_BUDGET = 1 << 20
+# The VPU popcount scratch is chunked over OCHUNK output channels and sized
+# against SCRATCH_BUDGET (both from kernels/xnor_matmul.py).
 
 
 def _gather_span(block: jnp.ndarray, *, hs: int, ws: int, fh: int,
@@ -68,29 +66,30 @@ def _gather_span(block: jnp.ndarray, *, hs: int, ws: int, fh: int,
     return jnp.concatenate(cols, axis=-1).reshape(hs * ws, fh * fw * cw)
 
 
-def _conv_counts(pm: jnp.ndarray, w: jnp.ndarray, *, variant: str, k: int,
-                 npad: int) -> jnp.ndarray:
-    """(P, L) patch words × (O, L) filter words → (P, O) int32 agree-counts.
+def _repack(bits: jnp.ndarray) -> jnp.ndarray:
+    """(P, C) int32 {0,1} bits → (P, C/32) int32 words, LSB-first (the
+    `core/bitpack.py::pack_bits` layout).
 
-    "vpu": XNOR + popcount (eq. 5), chunked over O so the (P, chunk, L)
-    scratch stays bounded. "mxu": unpack both operands to ±1 bf16 and use
-    the matrix unit — y_l = (k + dot − npad) / 2, exact for k ≤ 2²⁴.
+    Runs on the MXU: byte ``q`` of every word is one dot with a
+    (C, C/32) matrix holding 2^b at (32·w + 8·q + b, w). Exact: bits and
+    powers ≤ 2^7 are exact in bf16, byte sums ≤ 255 exact in f32. (A
+    shift-and-sum would split the lane axis into (C/32, 32), which Mosaic
+    cannot reshape.)
     """
-    o, ll = w.shape
-    if variant == "mxu":
-        a_pm1 = _unpack_pm1(pm, jnp.bfloat16)
-        w_pm1 = _unpack_pm1(w, jnp.bfloat16)
-        dot_p = jax.lax.dot_general(a_pm1, w_pm1, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        return (k + dot_p.astype(jnp.int32) - npad) // 2
-    outs = []
-    for oc in range(0, o, OCHUNK):
-        wc = jax.lax.slice(w, (oc, 0), (min(oc + OCHUNK, o), ll))
-        x = jnp.bitwise_xor(pm[:, None, :], wc[None, :, :])
-        agree = jax.lax.population_count(
-            jnp.bitwise_not(x).astype(jnp.uint32)).astype(jnp.int32)
-        outs.append(agree.sum(axis=-1) - npad)
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    c = bits.shape[1]
+    ch = jax.lax.broadcasted_iota(jnp.int32, (c, c // PACK), 0)
+    wd = jax.lax.broadcasted_iota(jnp.int32, (c, c // PACK), 1)
+    b16 = bits.astype(jnp.float32).astype(jnp.bfloat16)
+    words = None
+    for q in range(4):
+        sel = ((ch >> 5) == wd) & (((ch & 31) >> 3) == q)
+        mat = jnp.where(sel, jnp.left_shift(1, ch & 7), 0)
+        byte = jax.lax.dot_general(
+            b16, mat.astype(jnp.float32).astype(jnp.bfloat16),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        byte = byte.astype(jnp.int32) << (8 * q)
+        words = byte if words is None else words | byte
+    return words
 
 
 def _fused_pair_kernel(a_ref, wa_ref, ca_ref, fa_ref, wb_ref, cb_ref, fb_ref,
@@ -116,11 +115,14 @@ def _fused_pair_kernel(a_ref, wa_ref, ca_ref, fa_ref, wb_ref, cb_ref, fb_ref,
     block = a_ref[0, pl.ds(i * th * pf, ha + fha - 1),
                   pl.ds(j * tw * pf, wa + fwa - 1), :]
     pm_a = _gather_span(block, hs=ha, ws=wa, fh=fha, fw=fwa)
-    y_a = _conv_counts(pm_a, wa_ref[...], variant=variant, k=ka, npad=npad_a)
-    # conv A epilogue: eq. 8 NormBinarize → {0,1} bits (kept in registers)
-    ge = y_a.astype(jnp.float32) >= ca_ref[0][None, :]
-    bits = jnp.where(fa_ref[0][None, :] != 0, ~ge, ge)
-    bits = bits.reshape(ha, wa, oa).astype(jnp.uint32)
+    y_a = _agree_counts(pm_a, wa_ref[...], variant=variant, k=ka,
+                        npad=npad_a)
+    # conv A epilogue: eq. 8 NormBinarize → {0,1} bits, re-packed along
+    # the channel axis. This packed intermediate map is the tensor the
+    # unfused path writes to and reads back from HBM; here it never leaves
+    # VMEM.
+    bits = _norm_binarize(y_a, ca_ref[...], fa_ref[...])
+    words = _repack(bits).reshape(ha, wa, oa // PACK)
     # Halo positions outside the real A-output map must read as bit 0 (−1):
     # that is exactly the SAME-padding the unfused conv-B call would see.
     gr = (jax.lax.broadcasted_iota(jnp.int32, (ha, wa, 1), 0)
@@ -128,18 +130,12 @@ def _fused_pair_kernel(a_ref, wa_ref, ca_ref, fa_ref, wb_ref, cb_ref, fb_ref,
     gc = (jax.lax.broadcasted_iota(jnp.int32, (ha, wa, 1), 1)
           + j * tw * pf - (fwb // 2))
     valid = (gr >= 0) & (gr < h_img) & (gc >= 0) & (gc < w_img)
-    bits = jnp.where(valid, bits, jnp.uint32(0))
-    # Re-pack along the channel axis (LSB-first, the bitpack.pack_bits
-    # layout). This packed intermediate map is the tensor the unfused path
-    # writes to and reads back from HBM; here it never leaves VMEM.
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (PACK,), 0)
-    words = jnp.sum(bits.reshape(ha, wa, oa // PACK, PACK) << shifts,
-                    axis=-1, dtype=jnp.uint32).astype(jnp.int32)
+    words = jnp.where(valid, words, 0)
     pm_b = _gather_span(words, hs=pf * th, ws=pf * tw, fh=fhb, fw=fwb)
-    y_b = _conv_counts(pm_b, wb_ref[...], variant=variant, k=kb, npad=npad_b)
+    y_b = _agree_counts(pm_b, wb_ref[...], variant=variant, k=kb,
+                        npad=npad_b)
     # conv B epilogue: NormBinarize, then the optional trailing 2×2 max-pool
-    ge = y_b.astype(jnp.float32) >= cb_ref[0][None, :]
-    bit = jnp.where(fb_ref[0][None, :] != 0, ~ge, ge).astype(jnp.int32)
+    bit = _norm_binarize(y_b, cb_ref[...], fb_ref[...])
     bit = bit.reshape(pf * th, pf * tw, ob)
     if pf == 2:
         # pool on bits commutes with the monotone threshold: max where the
@@ -193,19 +189,15 @@ def halo_scratch(th: int, tw: int, *, pf: int, fhb: int, fwb: int, oa: int,
 
 def pick_tiles(ho: int, wo: int, *, pf: int, fhb: int, fwb: int, oa: int,
                la: int, budget: int = SCRATCH_BUDGET) -> tuple[int, int]:
-    """Largest power-of-two tiles whose halo popcount scratch fits ``budget``
-    (``halo_scratch``), halving the larger dimension first."""
+    """The tallest power-of-two tile whose halo popcount scratch fits
+    ``budget`` (``halo_scratch``); the width is ``tile_width(wo)``. Height 1
+    is the floor even over budget (the scratch is chunked in-kernel)."""
     from repro.kernels.ops import _block_for
     th = _block_for(ho, TH, floor=1)
-    tw = _block_for(wo, TW, floor=1)
-    while th * tw > 1:
-        if halo_scratch(th, tw, pf=pf, fhb=fhb, fwb=fwb, oa=oa,
-                        la=la) <= budget:
-            break
-        if th >= tw:
-            th = max(1, th // 2)
-        else:
-            tw = max(1, tw // 2)
+    tw = tile_width(wo)
+    while th > 1 and halo_scratch(th, tw, pf=pf, fhb=fhb, fwb=fwb, oa=oa,
+                                  la=la) > budget:
+        th //= 2
     return th, tw
 
 

@@ -10,13 +10,8 @@ import jax
 
 
 def _make_mesh(shape, axes, devices=None):
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there,
-    # so omit the kwarg on older versions instead of crashing at call time.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, pods: int = 0):

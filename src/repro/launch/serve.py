@@ -30,7 +30,7 @@ import jax
 import numpy as np
 
 from repro import configs
-from repro.launch import mesh as mesh_lib
+from repro.launch import compile_cache, mesh as mesh_lib
 from repro.models import transformer, xnor_lm
 from repro.serve import ServingEngine
 
@@ -69,6 +69,7 @@ def main(argv=None):
                          "artifact halfway and assert zero recompiles")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     binary_lm = args.arch in configs.BINARY_LM_NAMES
     cfg = configs.get_config(args.arch, smoke=args.smoke, quant=args.quant)

@@ -55,6 +55,7 @@ import numpy as np
 from repro.configs import bcnn_cifar10 as pc
 from repro.core import bcnn
 from repro.data import SyntheticImages
+from repro.launch import compile_cache
 from repro.serve import BCNNEngine, drive_poisson
 
 
@@ -338,6 +339,7 @@ def main(argv=None):
                          "as a deployment artifact to DIR")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.artifact:
         from repro.core import bcnn_artifact

@@ -27,6 +27,7 @@ import argparse
 
 from repro.configs import bcnn_cifar10 as pc
 from repro.core import bcnn, bcnn_artifact
+from repro.launch import compile_cache
 from repro.train import bcnn_train
 
 
@@ -51,6 +52,7 @@ def main(argv=None):
     ap.add_argument("--eval-batches", type=int, default=4)
     ap.add_argument("--log-every", type=int, default=50)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     try:
         state, info = bcnn_train.train(

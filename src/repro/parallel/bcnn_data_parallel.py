@@ -57,14 +57,6 @@ from repro.parallel import sharding
 from repro.parallel.bcnn_pipeline import (PipelinedForward, StagePlan,
                                           pad_rows, plan_bcnn_stages)
 
-# jax.shard_map became a top-level alias after 0.4.x (same guard as
-# parallel/pipeline.py)
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 class DeploymentPlan(NamedTuple):
     """The 2-D (data × stage) deployment layout of a sharded forward.
 
@@ -163,8 +155,12 @@ class ShardedForward:
             def fwd(arrs, x01):
                 return bcnn.forward_packed(rebuild(arrs), x01, plan=plan)
 
-            self._chunk_fn = jax.jit(_shard_map(
-                fwd, mesh=mesh, in_specs=(P(), spec), out_specs=spec))
+            # check_vma=False: a pallas_call's output carries no
+            # varying-axes type, and the per-shard body has no collective
+            # that would need one
+            self._chunk_fn = jax.jit(jax.shard_map(
+                fwd, mesh=mesh, in_specs=(P(), spec), out_specs=spec,
+                check_vma=False))
             self._columns = None
         else:
             # 2-D plan: shard column s pipelines the 9 layers over its own

@@ -31,13 +31,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.throughput import balance_stages
 
-# jax.shard_map became a top-level alias after 0.4.x
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 # ---------------------------------------------------------------------------
 # stage planning from the analytic cost model
 # ---------------------------------------------------------------------------
@@ -146,7 +139,7 @@ def pipelined_forward(stack_params, x, *, mesh, axis: str, apply_fn,
     perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P())
     def run(stage_params, mb):
@@ -179,11 +172,7 @@ def pipelined_forward(stack_params, x, *, mesh, axis: str, apply_fn,
             return (out_buf, recv_next), None
 
         def _vary(a):   # mark the zero init as device-varying over the axis
-            if hasattr(jax.lax, "pvary"):
-                return jax.lax.pvary(a, (axis,))
-            if hasattr(jax.lax, "pcast"):
-                return jax.lax.pcast(a, (axis,), to="varying")
-            return a    # 0.4.x shard_map has no varying-axes types to mark
+            return jax.lax.pcast(a, (axis,), to="varying")
 
         (out_buf, _), _ = jax.lax.scan(
             tick, (_vary(jnp.zeros_like(mb)), _vary(jnp.zeros_like(mb[0]))),

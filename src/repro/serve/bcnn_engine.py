@@ -58,14 +58,6 @@ from repro.core import bcnn
 from repro.serve.slots import SlotScheduler, latency_stats
 
 
-def _resolve_path(path: str) -> str:
-    """"auto" → the Pallas MXU kernels on TPU, the XLA reference off-TPU
-    (interpret-mode Pallas is correct but far too slow to *serve* with)."""
-    if path == "auto":
-        return "mxu" if jax.default_backend() == "tpu" else "xla"
-    return path
-
-
 class BCNNEngine:
     """Continuous streaming engine over a one-shot image classifier.
 

@@ -106,6 +106,16 @@ LOGITS_GOLD = [[-37.9981, -1.9999, 9.9995, 0.0, 43.997803,
                 -63.996803, -13.999301, 7.9996, 23.998802, -5.999701]]
 
 
+def _golden_packed():
+    """The packed net behind LOGITS_GOLD: ``init`` at LOGITS_SEED under the
+    non-partitionable threefry PRNG (the default before JAX 0.5), in which
+    the goldens were computed."""
+    from repro.core import bcnn
+    with jax.threefry_partitionable(False):
+        params = bcnn.init(jax.random.PRNGKey(LOGITS_SEED))
+    return bcnn.fold_model(params)
+
+
 def _golden_input_tile():
     """Deterministic (2, 32, 32, 3) image tile in [0, 1] — a pure formula,
     so the fixture itself cannot drift with PRNG implementations."""
@@ -117,8 +127,7 @@ def _golden_input_tile():
 @pytest.mark.parametrize("conv_strategy", ["direct", "im2col"])
 def test_forward_packed_golden_logits(conv_strategy):
     from repro.core import bcnn
-    params = bcnn.init(jax.random.PRNGKey(LOGITS_SEED))
-    packed = bcnn.fold_model(params)
+    packed = _golden_packed()
     logits = bcnn.forward_packed(packed, jnp.asarray(_golden_input_tile()),
                                  path="xla", conv_strategy=conv_strategy)
     got = np.asarray(logits)
@@ -133,7 +142,7 @@ def test_forward_golden_logits_fused(deployment):
     bit-exact, so the checked-in tile needs no fused variant — on all three
     deployment forwards (single-device, stage-pipelined, data-parallel)."""
     from repro.core import bcnn
-    packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(LOGITS_SEED)))
+    packed = _golden_packed()
     if deployment == "single":
         fwd = bcnn.make_packed_forward(packed, path="xla", conv_fusion=True)
     elif deployment == "pipelined":

@@ -18,7 +18,7 @@ def _rand_pm1(rng, shape):
 
 
 SHAPES = [
-    (8, 64, 16),       # tiny, K<32*BKW (padding path)
+    (8, 64, 16),       # tiny, ragged K and N (padding path)
     (16, 256, 32),     # one packed step
     (128, 1024, 128),  # aligned to default blocks
     (130, 300, 70),    # ragged everything

@@ -41,7 +41,10 @@ GOLD_DECODE = [20, 4, 20, 20, 4, 12, 16, 7]  # 8 greedy steps
 
 @functools.lru_cache(maxsize=2)
 def _model(seed: int = 0):
-    params = xnor_lm.init(CFG, jax.random.PRNGKey(seed))
+    # The goldens were computed under the non-partitionable threefry PRNG
+    # (the default before JAX 0.5); pin it so the weights stay the same.
+    with jax.threefry_partitionable(False):
+        params = xnor_lm.init(CFG, jax.random.PRNGKey(seed))
     return params, xnor_lm.fold(CFG, params)
 
 
