@@ -267,6 +267,14 @@ def plan_layer_groups(start: int = 0, stop: int = N_LAYERS, *,
     return tuple(groups)
 
 
+def group_scope(group: tuple[int, ...]) -> str:
+    """The named scope of a layer group: ``conv1``…``conv6``, ``fc1``…
+    ``fc3``, and ``conv3_4``/``conv5_6`` for a fused pair."""
+    def one(i):
+        return f"conv{i + 1}" if i < 6 else f"fc{i - 5}"
+    return one(group[0]) + "".join(f"_{g + 1}" for g in group[1:])
+
+
 def apply_packed_group(packed: BCNNPacked, group: tuple[int, ...],
                        h: jnp.ndarray, *, path: str = "mxu",
                        conv_strategy: str | None = None,
@@ -280,20 +288,26 @@ def apply_packed_group(packed: BCNNPacked, group: tuple[int, ...],
     its own dataflow). With a ``plan``
     (`core/execution_plan.py::ExecutionPlan`) the path, per-layer strategy,
     and the fused pair's (th, tw) output tile all come from the plan.
+
+    The group's operations run under the named scope ``group_scope(group)``,
+    which a device trace carries in each operation's metadata; the
+    operations' own names (the kernels' custom calls) are unchanged.
     """
-    if len(group) == 1:
-        return apply_packed_layer(packed, group[0], h, path=path,
-                                  conv_strategy=conv_strategy, plan=plan)
-    i, j = group
-    if j != i + 1 or not 1 <= i < j <= 5:
-        raise ValueError(f"not a fusible binary-conv pair: {group}")
-    tiles = None
-    if plan is not None:
-        path = plan.path
-        tiles = plan.tiles_for(i)
-    return bconv.apply_packed_pair(packed.convs[i - 1], packed.convs[j - 1],
-                                   h, maxpool_b=CONV_SPECS[j][2], path=path,
-                                   tiles=tiles)
+    with jax.named_scope(group_scope(group)):
+        if len(group) == 1:
+            return apply_packed_layer(packed, group[0], h, path=path,
+                                      conv_strategy=conv_strategy, plan=plan)
+        i, j = group
+        if j != i + 1 or not 1 <= i < j <= 5:
+            raise ValueError(f"not a fusible binary-conv pair: {group}")
+        tiles = None
+        if plan is not None:
+            path = plan.path
+            tiles = plan.tiles_for(i)
+        return bconv.apply_packed_pair(packed.convs[i - 1],
+                                       packed.convs[j - 1], h,
+                                       maxpool_b=CONV_SPECS[j][2], path=path,
+                                       tiles=tiles)
 
 
 def forward_packed(packed: BCNNPacked, x01: jnp.ndarray,

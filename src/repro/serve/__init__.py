@@ -1,7 +1,7 @@
 from repro.serve.engine import ServingEngine            # noqa: F401
 from repro.serve.bcnn_engine import BCNNEngine, drive_poisson  # noqa: F401
-from repro.serve.slots import (Request, SlotScheduler,  # noqa: F401
-                               latency_stats)
+from repro.serve.slots import (Request, SlotScheduler, Span,  # noqa: F401
+                               SpanLog, latency_stats)
 from repro.serve.replica import EngineReplica, SwapTicket      # noqa: F401
 from repro.serve.autoscale import (AutoscaleConfig,     # noqa: F401
                                    FleetAutoscaler, ScaleEvent)

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bcnn
-from repro.serve.slots import SlotScheduler, latency_stats
+from repro.serve.slots import SlotScheduler, SpanLog, latency_stats
 
 
 class BCNNEngine:
@@ -81,6 +81,9 @@ class BCNNEngine:
         self.n_slots = n_slots
         self.input_shape = tuple(input_shape)
         self.sched = SlotScheduler(n_slots, clock=clock, history=history)
+        # off until ``spans.enable()``; see "Tracing the engine" in
+        # docs/SERVING.md for what each span covers
+        self.spans = SpanLog(self.sched.clock)
         self._x = np.zeros((n_slots, *self.input_shape), np.float32)
         self._self_jitting = hasattr(forward_fn, "cache_size")
         if self._self_jitting:
@@ -95,6 +98,8 @@ class BCNNEngine:
             # step_cache_size compile counter
             self._step_fn = jax.jit(lambda x: forward_fn(x))
         self._steps = 0
+        self._images = 0
+        self._occupancy = [0] * (n_slots + 1)   # steps by occupied slots
         self._batch_fn = None           # set by from_packed(data_shards=N)
         self._batch_threshold = 0
         self._n_classes = None          # known for from_packed engines
@@ -215,23 +220,39 @@ class BCNNEngine:
     def step(self) -> dict[int, np.ndarray]:
         """One engine tick: admit from the queue, run the fixed-shape
         forward, complete every occupied slot. Returns {rid: logits}."""
-        for i, req in self.sched.admit():
-            self._x[i] = req.payload
-        return self._flush()
+        spans = self.spans
+        with spans("engine.step") as step_id:
+            with spans("engine.admit"):
+                for i, req in self.sched.admit():
+                    self._x[i] = req.payload
+            return self._flush(step_id)
 
-    def _flush(self) -> dict[int, np.ndarray]:
+    def _flush(self, step_id: int | None = None) -> dict[int, np.ndarray]:
         """Run the forward over the slot buffer and complete every occupied
         slot (no admission — ``swap_packed`` uses this to drain in-flight
-        requests on the pre-swap weights)."""
-        if self.sched.n_occupied == 0:
+        requests on the pre-swap weights). ``step_id`` is the enclosing
+        ``engine.step`` span's id, stamped on each request served."""
+        n = self.sched.n_occupied
+        if n == 0:
             return {}
-        logits = np.asarray(
-            jax.block_until_ready(self._step_fn(jnp.asarray(self._x))))
+        spans = self.spans
+        with spans("engine.put"):
+            x = jnp.asarray(self._x)
+        with spans("engine.dispatch"):
+            y = self._step_fn(x)
+        with spans("engine.wait"):
+            y = jax.block_until_ready(y)
+        with spans("engine.readback"):
+            logits = np.asarray(y)
         self._steps += 1
+        self._images += n
+        self._occupancy[n] += 1
         results = {}
-        for i, req in self.sched.occupied():
-            self.sched.complete(i)
-            results[req.rid] = logits[i]
+        with spans("engine.complete"):
+            for i, req in self.sched.occupied():
+                self.sched.complete(i)
+                req.step = step_id
+                results[req.rid] = logits[i]
         return results
 
     def swap_packed(self, new_packed: bcnn.BCNNPacked
@@ -301,29 +322,50 @@ class BCNNEngine:
         traffic through one driving loop rather than interleaving
         ``classify_batch`` with pending ``submit``s.
         """
-        images = np.asarray(images, np.float32)
-        if images.ndim != 1 + len(self.input_shape) or \
-                images.shape[1:] != self.input_shape:
-            raise ValueError(f"batch shape {images.shape} != (N, "
-                             f"{', '.join(map(str, self.input_shape))})")
-        if len(images) == 0:
-            # zero images carry zero information: answer host-side before
-            # either route (the bulk path used to pay a full padded-chunk
-            # device round-trip here). Width is known for from_packed
-            # engines; 0 for opaque forwards. ``batch_cache_size`` is
-            # untouched — the bulk forward neither compiles nor runs.
-            return np.zeros((0, self._n_classes or 0), np.float32)
-        if self._batch_fn is not None and len(images) >= self._batch_threshold:
-            return np.asarray(
-                jax.block_until_ready(self._batch_fn(jnp.asarray(images))))
-        rids = [self.submit(img) for img in images]
-        out = self.run()
-        return np.stack([out[r] for r in rids])
+        spans = self.spans
+        with spans("engine.classify_batch"):
+            images = np.asarray(images, np.float32)
+            if images.ndim != 1 + len(self.input_shape) or \
+                    images.shape[1:] != self.input_shape:
+                raise ValueError(f"batch shape {images.shape} != (N, "
+                                 f"{', '.join(map(str, self.input_shape))})")
+            if len(images) == 0:
+                # zero images carry zero information: answer host-side
+                # before either route (the bulk path used to pay a full
+                # padded-chunk device round-trip here). Width is known for
+                # from_packed engines; 0 for opaque forwards.
+                # ``batch_cache_size`` is untouched — the bulk forward
+                # neither compiles nor runs.
+                return np.zeros((0, self._n_classes or 0), np.float32)
+            if self._batch_fn is not None and \
+                    len(images) >= self._batch_threshold:
+                with spans("bulk.put"):
+                    x = jnp.asarray(images)
+                with spans("bulk.dispatch"):
+                    y = self._batch_fn(x)
+                with spans("bulk.wait"):
+                    y = jax.block_until_ready(y)
+                with spans("bulk.readback"):
+                    return np.asarray(y)
+            rids = [self.submit(img) for img in images]
+            out = self.run()
+            return np.stack([out[r] for r in rids])
 
     # ------------------------------------------------------------ accounting
     @property
     def steps_executed(self) -> int:
         return self._steps
+
+    @property
+    def images_served(self) -> int:
+        """Images answered by the slot steps (the bulk path not counted)."""
+        return self._images
+
+    @property
+    def occupancy(self) -> list[int]:
+        """Slot steps by occupied slots: ``occupancy[k]`` steps ran with
+        ``k`` of the ``n_slots`` slots live."""
+        return list(self._occupancy)
 
     @property
     def batch_forward(self):

@@ -11,7 +11,9 @@ slot. What differs per engine is the step itself (autoregressive decode in
 * monotone request-id assignment and a FIFO admission queue,
 * slot occupancy and reuse (a freed slot is immediately re-admittable),
 * per-request latency stamps (submit → admit → done) feeding the
-  p50/p95/p99 accounting in ``benchmarks/fig7.py --online``.
+  p50/p95/p99 accounting in ``benchmarks/fig7.py --online``,
+* the span log (``SpanLog``) that times the parts of an engine's step on
+  the same clock as those stamps.
 
 Slot occupancy is host-side *data*, never array *shape*: engines keep their
 device buffers at a fixed ``(n_slots, …)`` shape so the jit'd step compiles
@@ -20,12 +22,16 @@ pure host Python — no jax dependency — which keeps it trivially unit-testabl
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Iterable
+from typing import Any, Callable, Iterable, NamedTuple
 
 import numpy as np
+
+# the prefix of the span log's names in a profiler trace
+TRACE_PREFIX = "repro."
 
 
 @dataclass
@@ -48,6 +54,7 @@ class Request:
     t_submit: float | None = None
     t_admit: float | None = None
     t_done: float | None = None
+    step: int | None = None         # id of the step span that served it
 
     @property
     def latency(self) -> float | None:
@@ -66,6 +73,109 @@ class Request:
         if self.t_admit is None or self.t_submit is None:
             return None
         return self.t_admit - self.t_submit
+
+
+class Span(NamedTuple):
+    """One closed span of a ``SpanLog``, its times on the log's clock."""
+    name: str
+    t0: float
+    t1: float
+    id: int
+    parent: int             # the enclosing span's id, -1 at the top level
+
+
+_OFF = contextlib.nullcontext()
+
+
+class SpanLog:
+    """Named, nested spans on one clock, kept in a bounded ring.
+
+    An engine owns one, beside its ``SlotScheduler``, on the scheduler's
+    clock, so spans and request stamps share one timeline. The engine
+    marks its parts as ``with log("engine.put"): ...``. Off (the default)
+    that costs one attribute check and allocates nothing: the call returns
+    a shared no-op context, and ``as`` binds None.
+
+    ``enable`` allocates the ring and turns the log on. Each span then
+    records its name, start, end, id (0, 1, 2, … in the order spans open)
+    and its parent's id, and ``as`` binds the id. It also opens a
+    ``jax.profiler.TraceAnnotation`` named ``TRACE_PREFIX + name``, so a
+    running profiler puts the span on the device trace's clock. Past
+    ``capacity`` spans the newest overwrite the oldest (``dropped`` counts
+    them). ``read`` returns the closed spans still held, oldest first.
+    Like the engine, it is driven by one thread.
+    """
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter):
+        self.on = False
+        self._clock = clock
+        self._cap = 0
+        self._n = 0
+
+    def enable(self, capacity: int = 1 << 18) -> None:
+        """Start recording into a fresh ring of ``capacity`` spans."""
+        if capacity < 1:
+            raise ValueError(f"capacity must be >= 1, got {capacity}")
+        import jax
+        self._annotation = jax.profiler.TraceAnnotation
+        self._names: list[str] = []
+        self._codes: dict[str, int] = {}
+        self._code = np.zeros(capacity, np.int32)
+        self._t0 = np.zeros(capacity, np.float64)
+        self._t1 = np.full(capacity, np.nan)
+        self._parent = np.zeros(capacity, np.int64)
+        self._stack: list[tuple[int, Any]] = []
+        self._pending = ""
+        self._cap, self._n = capacity, 0
+        self.on = True
+
+    def __call__(self, name: str):
+        if not self.on:
+            return _OFF
+        self._pending = name
+        return self
+
+    def __enter__(self) -> int:
+        name = self._pending
+        code = self._codes.get(name)
+        if code is None:
+            code = self._codes[name] = len(self._names)
+            self._names.append(name)
+        ann = self._annotation(TRACE_PREFIX + name)
+        ann.__enter__()
+        sid = self._n
+        k = sid % self._cap
+        self._code[k] = code
+        self._parent[k] = self._stack[-1][0] if self._stack else -1
+        self._t1[k] = np.nan
+        self._stack.append((sid, ann))
+        self._n += 1
+        self._t0[k] = self._clock()
+        return sid
+
+    def __exit__(self, *exc) -> bool:
+        t1 = self._clock()
+        sid, ann = self._stack.pop()
+        self._t1[sid % self._cap] = t1
+        ann.__exit__(None, None, None)
+        return False
+
+    @property
+    def dropped(self) -> int:
+        """Spans overwritten because the ring was full."""
+        return max(0, self._n - self._cap)
+
+    def read(self) -> list[Span]:
+        """The closed spans the ring still holds, oldest first."""
+        if not self._cap:
+            return []
+        ids = np.arange(self.dropped, self._n)
+        ids = ids[~np.isnan(self._t1[ids % self._cap])]
+        k = ids % self._cap
+        return [Span(self._names[c], t0, t1, i, p) for c, t0, t1, i, p
+                in zip(self._code[k].tolist(), self._t0[k].tolist(),
+                       self._t1[k].tolist(), ids.tolist(),
+                       self._parent[k].tolist())]
 
 
 class SlotScheduler:

@@ -152,3 +152,31 @@ def test_default_plan_forward_compiles(one_chip, monkeypatch):
                    tuple(_sds(one_chip, a.shape, a.dtype) for a in arrays),
                    _sds(one_chip, (BATCH, 32, 32, 3), jnp.float32))
     assert "tpu_custom_call" in hlo
+
+
+def test_layer_scopes_leave_the_kernel_names(one_chip, monkeypatch):
+    """Each layer group runs under its named scope (``bcnn.group_scope``),
+    which reaches the operations' metadata; the kernels' custom calls keep
+    the names a device trace finds them by."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
+    plan = execution_plan.default_plan(packed, backend="tpu")
+    arrays, rebuild = bcnn.split_packed(packed)
+
+    def step(arrs, x01):
+        return bcnn.forward_packed(rebuild(arrs), x01, plan=plan)
+
+    hlo = _compile(step,
+                   tuple(_sds(one_chip, a.shape, a.dtype) for a in arrays),
+                   _sds(one_chip, (BATCH, 32, 32, 3), jnp.float32))
+    scopes = []
+    for line in hlo.splitlines():
+        if "custom-call(" in line and "pallas_call" in line:
+            name = line.split(" = ", 1)[0].strip().lstrip("%")
+            assert name.rpartition(".")[0] in (
+                "xnor_conv2d", "xnor_conv2d_pair", "xnor_matmul"), name
+            # op_name="jit(step)/<scope>/jit(<kernel>)/pallas_call"
+            scopes.append(line.split('op_name="', 1)[1].split("/")[1])
+    # one kernel per group, CONV-1 (a plain conv) aside
+    groups = bcnn.plan_layer_groups(conv_fusion=plan.conv_fusion)
+    assert sorted(scopes) == sorted(bcnn.group_scope(g) for g in groups[1:])
