@@ -61,37 +61,58 @@ def _unpack_pm1(words: jnp.ndarray, dtype) -> jnp.ndarray:
     return jnp.concatenate(planes, axis=1).astype(jnp.float32).astype(dtype)
 
 
+def _kchunks(ll: int) -> list[tuple[int, int]]:
+    """Word ranges ``[k0, k1)`` of the MXU path's ``KCHUNK``-word unpack
+    chunks. Both operands of each dot are unpacked over the same ranges, so
+    their bit-plane orders (``_unpack_pm1``) agree chunk by chunk."""
+    return [(k0, min(k0 + KCHUNK, ll)) for k0 in range(0, ll, KCHUNK)]
+
+
+def _mxu_agree_counts(pm: jnp.ndarray, w_chunk, o: int, *, k: int,
+                      npad: int) -> jnp.ndarray:
+    """(P, L) packed rows × O filters → (P, O) int32 y_l on the matrix unit.
+
+    Unpacks ``pm`` to ±1 bf16 one ``_kchunks`` chunk at a time and dots it
+    with ``w_chunk(k0, k1, oc, oe)``: filters ``oc:oe`` over words
+    ``k0:k1`` as ±1 bf16 in ``_unpack_pm1``'s order, unpacked on the spot
+    (``_agree_counts``) or read from a scratch unpacked once
+    (``kernels/xnor_conv.py``). y_l = (k + dot − npad) / 2, exact for
+    k ≤ 2²⁴ (pad bits agree: (−1)·(−1)).
+    """
+    p = pm.shape[0]
+    och = min(o, OCHUNK)
+    dots = [None] * len(range(0, o, och))
+    for k0, k1 in _kchunks(pm.shape[1]):
+        a_pm1 = _unpack_pm1(jax.lax.slice(pm, (0, k0), (p, k1)),
+                            jnp.bfloat16)
+        for n, oc in enumerate(range(0, o, och)):
+            d = jax.lax.dot_general(
+                a_pm1, w_chunk(k0, k1, oc, min(oc + och, o)),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            dots[n] = d if dots[n] is None else dots[n] + d
+    dot_p = dots[0] if len(dots) == 1 else jnp.concatenate(dots, 1)
+    return (k + dot_p.astype(jnp.int32) - npad) // 2
+
+
 def _agree_counts(pm: jnp.ndarray, w: jnp.ndarray, *, variant: str, k: int,
                   npad: int) -> jnp.ndarray:
     """(P, L) packed rows × (O, L) packed filters → (P, O) int32 y_l.
 
     "mxu": unpack both operands to ±1 bf16 and use the matrix unit, in
-    chunks of ``KCHUNK`` words × ``OCHUNK`` filters — y_l = (k + dot −
-    npad) / 2, exact for k ≤ 2²⁴ (pad bits agree: (−1)·(−1)). "vpu": XNOR +
-    popcount (eq. 5) over the full word axis, chunked over O (lane-aligned)
-    and P (sublane multiples) so each (P, chunk, L) scratch — L padded to
-    whole 128-lane vregs — fits ``SCRATCH_BUDGET``. Shared by every binary
-    kernel in this package.
+    chunks of ``KCHUNK`` words × ``OCHUNK`` filters (``_mxu_agree_counts``).
+    "vpu": XNOR + popcount (eq. 5) over the full word axis, chunked over O
+    (lane-aligned) and P (sublane multiples) so each (P, chunk, L) scratch —
+    L padded to whole 128-lane vregs — fits ``SCRATCH_BUDGET``. Shared by
+    every binary kernel in this package.
     """
-    p, ll = pm.shape
     o = w.shape[0]
-    och = min(o, OCHUNK)
     if variant == "mxu":
-        dots = [None] * len(range(0, o, och))
-        for k0 in range(0, ll, KCHUNK):
-            k1 = min(k0 + KCHUNK, ll)
-            a_pm1 = _unpack_pm1(jax.lax.slice(pm, (0, k0), (p, k1)),
-                                jnp.bfloat16)
-            for n, oc in enumerate(range(0, o, och)):
-                w_pm1 = _unpack_pm1(
-                    jax.lax.slice(w, (oc, k0), (min(oc + och, o), k1)),
-                    jnp.bfloat16)
-                d = jax.lax.dot_general(
-                    a_pm1, w_pm1, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                dots[n] = d if dots[n] is None else dots[n] + d
-        dot_p = dots[0] if len(dots) == 1 else jnp.concatenate(dots, 1)
-        return (k + dot_p.astype(jnp.int32) - npad) // 2
+        return _mxu_agree_counts(
+            pm, lambda k0, k1, oc, oe: _unpack_pm1(
+                jax.lax.slice(w, (oc, k0), (oe, k1)), jnp.bfloat16),
+            o, k=k, npad=npad)
+    p, ll = pm.shape
+    och = min(o, OCHUNK)
     lanes = -(-ll // 128) * 128
     pch = max(8, SCRATCH_BUDGET // (och * lanes) // 8 * 8)
     rows = []

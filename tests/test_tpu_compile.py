@@ -22,7 +22,8 @@ from repro.kernels import ops
 
 BATCH = 4               # the serving engine's slot count (SERVE_N_SLOTS)
 # Table 2 binary conv layers: name → (C, O, H)
-CONVS = {"conv2": (128, 128, 32), "conv4": (256, 256, 16),
+CONVS = {"conv2": (128, 128, 32), "conv3": (128, 256, 16),
+         "conv4": (256, 256, 16), "conv5": (256, 512, 8),
          "conv6": (512, 512, 8)}
 # fused pairs: name → (C, OA, OB, H); the second member max-pools
 PAIRS = {"conv3_4": (128, 256, 256, 16), "conv5_6": (256, 512, 512, 8)}

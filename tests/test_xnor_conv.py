@@ -5,7 +5,11 @@ Four implementations must agree bit-for-bit on the integer agree-counts y_l
 XNOR-matmul lowering, and the pure-jnp oracle. Sweeps odd H/W, stride,
 padding, non-multiple-of-32 channels, and fused/unfused epilogues.
 """
+import functools
+from collections import Counter
+
 import jax
+import jax.extend.core as jcore
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -23,6 +27,8 @@ CONFIGS = [
     (8, 8, 32, 8, 3, 1, 0),      # no spatial padding
     (6, 6, 16, 8, 1, 1, 0),      # 1×1 conv, C < 32
     (10, 6, 64, 24, 5, 2, 2),    # 5×5, stride 2, multi-word channels
+    (8, 8, 32, 256, 3, 1, 1),    # two output-channel blocks (mxu: refill)
+    (8, 8, 512, 16, 3, 1, 1),    # 144 filter words: two KCHUNK chunks
 ]
 
 
@@ -64,6 +70,43 @@ def test_direct_conv_fused_normbinarize(h, w, c, o, f, stride, pad, path):
                     ).astype(np.int8)
     assert bits.dtype == jnp.int8
     np.testing.assert_array_equal(np.asarray(bits), want)
+
+
+def _pallas_kernels(jaxpr):
+    """Kernel bodies of the pallas_calls in ``jaxpr``, nested jits too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["jaxpr"]
+        for v in eqn.params.values():
+            if isinstance(v, jcore.ClosedJaxpr):
+                yield from _pallas_kernels(v.jaxpr)
+
+
+def _unpack_rows(jaxpr) -> Counter:
+    """Row counts of the bit-plane shifts in ``jaxpr``'s own equations
+    (nested jaxprs excluded): one per plane of each ``_unpack_pm1``."""
+    return Counter(e.outvars[0].aval.shape[0] for e in jaxpr.eqns
+                   if e.primitive.name == "shift_right_arithmetic")
+
+
+def test_mxu_conv_unpacks_filters_once_per_block():
+    """The mxu kernel unpacks its (128-row) filter block only under the
+    first-program guard; each output tile's body unpacks its 64 patch rows
+    alone. C = 512 gives two KCHUNK chunks, unpacked alike on both sides."""
+    c, o = 512, 256
+    jaxpr = jax.make_jaxpr(functools.partial(
+        ops.xnor_conv2d, k=9 * c, fh=3, fw=3, path="mxu", interpret=True))(
+        jax.ShapeDtypeStruct((2, 8, 8, c), jnp.int8),
+        jax.ShapeDtypeStruct((o, 9 * c // 32), jnp.int32)).jaxpr
+    [body] = _pallas_kernels(jaxpr)
+    guards = [e for e in body.eqns if e.primitive.name == "cond"]
+    assert len(guards) == 1
+    guarded = sum((_unpack_rows(b.jaxpr) for b in guards[0].params["branches"]),
+                  Counter())
+    tile = _unpack_rows(body)
+    assert set(tile) == {kconv.TH * kconv.TW}
+    assert set(guarded) == {kconv.BO}
+    assert tile[kconv.TH * kconv.TW] == guarded[kconv.BO] > 0
 
 
 # ---------------------------------------------------------------------------
