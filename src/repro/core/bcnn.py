@@ -26,8 +26,9 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core import bconv, bitpack, blinear
+from repro.core import bconv, bitpack, blinear, packed_model
 from repro.core.binarize import binarize_ste, quantize_input_6bit, quantize_weight_2bit
 from repro.core.normbinarize import BNParams, norm_only
 
@@ -158,12 +159,35 @@ def forward_eval(params: BCNNParams, x01: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 class BCNNPacked(NamedTuple):
+    """The packed Table 2 network; a `core/packed_model.py::PackedModel`."""
     conv1: bconv.FpConvParams          # first layer stays fixed-point (eq. 7)
     convs: tuple                       # BConvPacked × 5
     fcs: tuple                         # BLinearPacked × 2 (FC-1, FC-2)
     fc3_w_words: jnp.ndarray           # packed FC-3 weights
     fc3_bn: BNParams                   # FC-3 ends with Norm (no binarize)
     fc3_k: int
+
+    @property
+    def input_shape(self) -> tuple[int, int, int]:
+        return (32, 32, 3)
+
+    @property
+    def input_dtype(self) -> np.dtype:
+        return np.dtype(np.float32)
+
+    @property
+    def n_classes(self) -> int:
+        return self.fc3_w_words.shape[0]
+
+    def check_plan(self, plan) -> None:
+        """A plan carries one conv strategy per layer of the nine."""
+        if len(plan.conv_strategy) != N_LAYERS:
+            raise ValueError(
+                f"conv_strategy must have {N_LAYERS} entries, got "
+                f"{len(plan.conv_strategy)}")
+
+    def forward(self, x01: jnp.ndarray, plan) -> jnp.ndarray:
+        return forward_packed(self, x01, plan=plan)
 
 
 def fold_model(params: BCNNParams) -> BCNNPacked:
@@ -339,133 +363,19 @@ def forward_packed(packed: BCNNPacked, x01: jnp.ndarray,
 
 # ---------------------------------------------------------------------------
 # Weight hot-swap plumbing: arrays ride as jit ARGUMENTS, statics stay closed
+# (shared by every packed model: core/packed_model.py)
 # ---------------------------------------------------------------------------
 
-def _is_weight_array(x) -> bool:
-    """Array-like packed leaf (vs the static Python ints/floats/None the
-    packed NamedTuples also carry: k, fh/fw, fc3_k, BN eps)."""
-    return hasattr(x, "shape") and hasattr(x, "dtype")
-
-
-def split_packed(packed: BCNNPacked):
-    """Split a packed net into (array leaves, rebuild closure).
-
-    ``forward_packed`` cannot be jit'd with ``packed`` as one argument: the
-    packed NamedTuples mix arrays with static Python ints (k, filter sizes)
-    that jit would trace into abstract values, breaking the kernels'
-    ``static_argnames``. This split is the hot-swap contract: the *arrays*
-    ride as a flat tuple of jit arguments (so two packed nets with
-    identical shapes/dtypes hit the same compiled executable — zero
-    recompiles on ``BCNNEngine.swap_packed``), while ``rebuild(arrays)``
-    re-threads them through the static skeleton inside the trace.
-    """
-    leaves, treedef = jax.tree_util.tree_flatten(
-        packed, is_leaf=lambda x: x is None)
-    mask = tuple(_is_weight_array(l) for l in leaves)
-    arrays = tuple(l for l, m in zip(leaves, mask) if m)
-    statics = tuple(None if m else l for l, m in zip(leaves, mask))
-
-    def rebuild(arrs) -> BCNNPacked:
-        it = iter(arrs)
-        return jax.tree_util.tree_unflatten(
-            treedef, [next(it) if m else s for m, s in zip(mask, statics)])
-
-    return arrays, rebuild
-
-
-def assert_swap_compatible(old: BCNNPacked, new: BCNNPacked) -> tuple:
-    """Validate that ``new`` can hot-swap into a forward built from ``old``
-    with ZERO recompiles: identical tree structure, identical statics
-    (k/fh/fw/eps), identical array shapes and dtypes. Returns the new
-    array-leaf tuple (``split_packed`` order) on success; raises
-    ValueError with the first mismatch otherwise."""
-    lo, to = jax.tree_util.tree_flatten(old, is_leaf=lambda x: x is None)
-    ln, tn = jax.tree_util.tree_flatten(new, is_leaf=lambda x: x is None)
-    if to != tn:
-        raise ValueError(f"packed tree structure differs: {to} != {tn}")
-    for i, (a, b) in enumerate(zip(lo, ln)):
-        if _is_weight_array(a) != _is_weight_array(b):
-            raise ValueError(f"leaf {i}: array/static kind mismatch "
-                             f"({type(a).__name__} vs {type(b).__name__})")
-        if _is_weight_array(a):
-            if tuple(a.shape) != tuple(b.shape) or a.dtype != b.dtype:
-                raise ValueError(
-                    f"leaf {i}: shape/dtype mismatch {a.shape}/{a.dtype} vs "
-                    f"{b.shape}/{b.dtype} — a swap must come from the same "
-                    f"architecture (fold_model of identically-shaped params)")
-        elif a != b:
-            raise ValueError(f"leaf {i}: static mismatch {a!r} != {b!r} "
-                             f"(k/filter-size/eps must be identical)")
-    return tuple(l for l in ln if _is_weight_array(l))
-
-
-class PackedForward:
-    """Self-jitting, hot-swappable single-device packed forward.
-
-    Callable ``(N, H, W, C) float32 → (N, n_classes) float32`` with a
-    shape-only jit signature: the weight arrays are passed as jit
-    *arguments* (statics closed over via ``split_packed``), so
-
-    * the jit compiles exactly once per input shape (``cache_size()`` — the
-      zero-recompile contract ``serve/bcnn_engine.py`` relies on), and
-    * ``swap(new_packed)`` replaces the weights under live traffic with no
-      recompilation at all: identical shapes/dtypes → same executable.
-    """
-
-    def __init__(self, packed: BCNNPacked, *, path: str = "mxu",
-                 conv_strategy: str | None = None,
-                 conv_fusion: bool | None = None,
-                 plan=None):
-        if plan is None:
-            from repro.core import execution_plan
-            plan = execution_plan.build_plan(packed, path=path,
-                                             conv_strategy=conv_strategy,
-                                             conv_fusion=conv_fusion)
-        self._packed = packed
-        self._plan = plan
-        arrays, rebuild = split_packed(packed)
-        self._arrays = arrays
-
-        def fwd(arrs, x01: jnp.ndarray) -> jnp.ndarray:
-            return forward_packed(rebuild(arrs), x01, plan=plan)
-
-        self._jit = jax.jit(fwd)
-
-    @property
-    def packed(self) -> BCNNPacked:
-        """The packed net currently being served."""
-        return self._packed
-
-    @property
-    def plan(self):
-        """The `core/execution_plan.py::ExecutionPlan` closed over the jit."""
-        return self._plan
-
-    def __call__(self, x01: jnp.ndarray) -> jnp.ndarray:
-        return self._jit(self._arrays, x01)
-
-    def lower(self, x01: jnp.ndarray):
-        """The step lowered for ``x01``'s shape, without running it —
-        ``.compile().as_text()`` is the HLO the device executes."""
-        return self._jit.lower(self._arrays, x01)
-
-    def swap(self, new_packed: BCNNPacked) -> None:
-        """Replace the served weights; zero recompiles (shapes must match,
-        checked by ``assert_swap_compatible``)."""
-        self._arrays = assert_swap_compatible(self._packed, new_packed)
-        self._packed = new_packed
-
-    def cache_size(self) -> int:
-        """Distinct compilations of the jit'd forward (1 per input shape,
-        unchanged by any number of ``swap``s)."""
-        return int(self._jit._cache_size())
+split_packed = packed_model.split
+assert_swap_compatible = packed_model.check_swap
 
 
 def make_packed_forward(packed: BCNNPacked, *, path: str = "mxu",
                         conv_strategy: str | None = None,
                         conv_fusion: bool | None = None,
-                        plan=None) -> PackedForward:
-    """Close the packed statics over ``forward_packed`` → a ``PackedForward``.
+                        plan=None) -> packed_model.PackedForward:
+    """Close the packed statics over ``forward_packed`` → a
+    `core/packed_model.py::PackedForward`.
 
     The returned object is a plain ``x01 → logits`` callable with a
     shape-only jit signature — it compiles exactly once per input shape,
@@ -479,8 +389,12 @@ def make_packed_forward(packed: BCNNPacked, *, path: str = "mxu",
     ``plan`` — an `core/execution_plan.py::ExecutionPlan` carrying every
     kernel choice at once; the other kwargs become no-ops when it is given.
     """
-    return PackedForward(packed, path=path, conv_strategy=conv_strategy,
-                         conv_fusion=conv_fusion, plan=plan)
+    if plan is None:
+        from repro.core import execution_plan
+        plan = execution_plan.build_plan(packed, path=path,
+                                         conv_strategy=conv_strategy,
+                                         conv_fusion=conv_fusion)
+    return packed_model.PackedForward(packed, plan)
 
 
 def loss_fn(params: BCNNParams, x01: jnp.ndarray, labels: jnp.ndarray):

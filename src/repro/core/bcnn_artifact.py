@@ -53,7 +53,8 @@ from repro.core import bconv, blinear
 # _is_weight_array: the SAME leaf predicate the hot-swap path uses
 # (split_packed / assert_swap_compatible) — a loaded artifact is documented
 # as a valid swap payload, so the two must never diverge
-from repro.core.bcnn import BCNNPacked, _is_weight_array
+from repro.core.bcnn import BCNNPacked
+from repro.core.packed_model import _is_weight_array
 from repro.core.crc import crc32_array as _crc
 from repro.core.normbinarize import BNParams, NBThreshold
 

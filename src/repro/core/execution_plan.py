@@ -68,9 +68,12 @@ class ExecutionPlan:
     compilation and a weight hot-swap never invalidates it.
 
     path:          resolved kernel variant — "vpu" | "mxu" | "xla"
-    conv_strategy: per-layer resolved dataflow, length `core/bcnn.py`
-                   ``N_LAYERS``; "direct"/"im2col" on binary conv layers
-                   (indices 1..5), None elsewhere
+    conv_strategy: per-layer resolved dataflow of the Table 2 network,
+                   length `core/bcnn.py` ``N_LAYERS``; "direct"/"im2col" on
+                   binary conv layers (indices 1..5), None elsewhere;
+                   empty for a network with no such choice (Bi-Real Net).
+                   Each packed model checks the plan it is given
+                   (`core/packed_model.py::PackedModel.check_plan`)
     conv_fusion:   fuse same-resolution conv pairs into the
                    `kernels/xnor_conv_fused.py` megakernel
     group_tiles:   per fused pair ``(first_layer_idx, th, tw)`` — the
@@ -91,10 +94,6 @@ class ExecutionPlan:
     def __post_init__(self):
         if self.path not in PLAN_PATHS:
             raise ValueError(f"unknown kernel path {self.path!r}")
-        if len(self.conv_strategy) != bcnn.N_LAYERS:
-            raise ValueError(
-                f"conv_strategy must have {bcnn.N_LAYERS} entries, got "
-                f"{len(self.conv_strategy)}")
         if self.lm_mode not in ("bw", "xnor"):
             raise ValueError(f"unknown lm_mode {self.lm_mode!r}")
 
@@ -231,6 +230,10 @@ def build_plan(packed, *, path: str = "auto",
     what the old threading did.
     """
     rpath = resolve_path(path, backend)
+    if not isinstance(packed, bcnn.BCNNPacked):
+        # a network with no per-layer conv choices: the path alone
+        return ExecutionPlan(path=rpath, conv_strategy=(), lm_mode=lm_mode,
+                             tuned=tuned)
     strategies = [None] * bcnn.N_LAYERS
     for idx in range(1, 6):
         fp = packed.convs[idx - 1]

@@ -166,12 +166,20 @@ def xnor_conv2d(a_bits: jnp.ndarray, w_words: jnp.ndarray, *, k: int,
     bo = kconv.BO                       # lane blocks: always 128 wide
     ho_p = -(-ho // th) * th
     wo_p = -(-wo // tw) * tw
-    hp_need = (ho_p - 1) * stride + fh
-    wp_need = (wo_p - 1) * stride + fw
+    if stride == 1:
+        hp_need = (ho_p - 1) * stride + fh
+        wp_need = (wo_p - 1) * stride + fw
+    else:
+        # whole phases (kconv.split_phases): each holds the tile grid plus
+        # the taps' reach, (f - 1) // stride rows and columns
+        hp_need = stride * (ho_p + (fh - 1) // stride)
+        wp_need = stride * (wo_p + (fw - 1) // stride)
     aw = jnp.pad(aw, ((0, 0),
                       (ph, max(0, hp_need - h - ph)),
                       (pw, max(0, wp_need - w - pw)),
                       (0, 0)))
+    if stride > 1:
+        aw = kconv.split_phases(aw[:, :hp_need, :wp_need], stride)
     w_p, o_true = _pad_rows(w_words, bo)
     cc = ff = None
     if thr_c is not None:

@@ -83,16 +83,50 @@ def pack_conv_weights(w: jnp.ndarray) -> jnp.ndarray:
     return bitpack.pack_pm1(w).reshape(o, -1)
 
 
+def split_phases(a_words: jnp.ndarray, stride: int) -> jnp.ndarray:
+    """(N, s·Hq, s·Wq, Cw) padded packed image → (N, s·s·Hq, Wq, Cw): its
+    ``stride`` × ``stride`` phases, phase (p, q) = rows p::s, columns q::s,
+    stacked along the rows in order p·s + q.
+
+    A strided conv's tap (dy, dx) at output (oi, oj) reads image row
+    s·oi + dy = s·(oi + dy//s) + dy%s: row oi + dy//s of phase row dy%s.
+    So every tap is a stride-1 window of one phase, which Mosaic lowers;
+    it refuses a strided slice of a vector.
+    """
+    n, hp, wp, kwc = a_words.shape
+    hq, wq = hp // stride, wp // stride
+    a = a_words.reshape(n, hq, stride, wq, stride, kwc)
+    return a.transpose(0, 2, 4, 1, 3, 5).reshape(n, stride * stride * hq, wq,
+                                                 kwc)
+
+
 def _gather_patches(a_ref, i, j, *, th: int, tw: int, fh: int, fw: int,
                     stride: int) -> jnp.ndarray:
     """Gather output tile (i, j)'s reception fields from the VMEM-resident
     image.
 
-    a_ref: (1, Hp, Wp, Cw) packed image block.
+    a_ref: (1, Hp, Wp, Cw) packed image block; for ``stride`` > 1 its
+    phases (``split_phases``), (1, s·s·Hq, Wq, Cw).
     Returns (th·tw, fh·fw·Cw) int32 patch words, ordered (dy, dx, cw) to
     match ``pack_conv_weights``.
     """
     kwc = a_ref.shape[3]
+    if stride > 1:
+        hq = a_ref.shape[1] // (stride * stride)
+        span_h, span_w = th + (fh - 1) // stride, tw + (fw - 1) // stride
+        phases = {}
+        cols = []
+        for dy in range(fh):
+            for dx in range(fw):
+                ph = (dy % stride) * stride + dx % stride
+                if ph not in phases:
+                    phases[ph] = a_ref[0, pl.ds(ph * hq + i * th, span_h),
+                                       pl.ds(j * tw, span_w), :]
+                oy, ox = dy // stride, dx // stride
+                cols.append(jax.lax.slice(phases[ph], (oy, ox, 0),
+                                          (oy + th, ox + tw, kwc)))
+        patches = jnp.concatenate(cols, axis=-1)
+        return patches.reshape(th * tw, fh * fw * kwc)
     span_h = (th - 1) * stride + fh
     span_w = (tw - 1) * stride + fw
     block = a_ref[0, pl.ds(i * th * stride, span_h),

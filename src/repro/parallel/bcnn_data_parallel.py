@@ -51,7 +51,8 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro.core import bcnn, execution_plan as xplan
+from repro.core import bcnn, execution_plan as xplan, packed_model
+from repro.core.packed_model import PackedModel
 from repro.launch.mesh import dp_axes, make_data_mesh
 from repro.parallel import sharding
 from repro.parallel.bcnn_pipeline import (PipelinedForward, StagePlan,
@@ -69,7 +70,7 @@ class DeploymentPlan(NamedTuple):
     n_stages: int
     micro_batch: int
     chunk: int
-    stage_plan: StagePlan
+    stage_plan: StagePlan | None    # None: a network other than Table 2
     conv_fusion: bool = False
     fused_groups: tuple = ()   # per stage: the plan_layer_groups partition
 
@@ -82,25 +83,29 @@ class DeploymentPlan(NamedTuple):
                 "n_stages": self.n_stages,
                 "micro_batch": self.micro_batch,
                 "chunk": self.chunk,
-                "stage_bounds": list(self.stage_plan.bounds),
+                "stage_bounds": (None if self.stage_plan is None
+                                 else list(self.stage_plan.bounds)),
                 "conv_fusion": bool(self.conv_fusion),
                 "fused_groups": [[list(g) for g in stage]
                                  for stage in self.fused_groups]}
 
 
 class ShardedForward:
-    """Callable: (N, 32, 32, 3) images → (N, 10) logits, batch-sharded.
+    """Callable: (N, *input_shape) inputs → (N, n_classes) logits,
+    batch-sharded, for any `core/packed_model.py::PackedModel`.
 
     Built by ``make_sharded_forward``. Accepts ANY batch size N (including
     0 and N < chunk) with zero recompiles: batches are processed in
     fixed-shape chunks of ``plan.chunk`` images, the ragged tail padded
     with zero images whose rows are sliced away again. Per-image results
     are independent (pure data parallelism — no cross-shard collective),
-    so output rows are bit-identical to ``core/bcnn.py::forward_packed``.
+    so output rows are bit-identical to the network's own forward
+    (``core/bcnn.py::forward_packed``, ``core/bireal.py::forward_packed``).
 
     With ``n_stages == 1`` the chunk function is one jit'd ``shard_map``
-    over the mesh's data axes. With ``n_stages > 1`` each shard column is
-    a ``parallel/bcnn_pipeline.py::PipelinedForward`` over its own stage
+    over the mesh's data axes. With ``n_stages > 1`` (the Table 2 network
+    only) each shard column is a
+    ``parallel/bcnn_pipeline.py::PipelinedForward`` over its own stage
     devices; the chunk is split host-side and the columns run
     concurrently via async dispatch.
 
@@ -110,7 +115,7 @@ class ShardedForward:
     inside ``benchmarks/fig7.py --offline``.
     """
 
-    def __init__(self, packed: bcnn.BCNNPacked, mesh, micro_batch: int, *,
+    def __init__(self, packed: PackedModel, mesh, micro_batch: int, *,
                  n_stages: int = 1, devices: Sequence | None = None,
                  path: str = "mxu", conv_strategy: str | None = None,
                  conv_fusion: bool | None = None,
@@ -121,12 +126,17 @@ class ShardedForward:
             plan = xplan.build_plan(packed, path=path,
                                     conv_strategy=conv_strategy,
                                     conv_fusion=conv_fusion)
+        packed.check_plan(plan)
         self.exec_plan = plan           # the ExecutionPlan (kernel choices)
         self.mesh = mesh
         shards = 1
         for a in dp_axes(mesh):
             shards *= mesh.shape[a]
-        stage_plan = plan_bcnn_stages(n_stages)
+        table2 = isinstance(packed, bcnn.BCNNPacked)
+        if not table2 and n_stages != 1:
+            raise ValueError("stage pipelining cuts the Table 2 network's "
+                             "nine layers; other networks take n_stages=1")
+        stage_plan = plan_bcnn_stages(n_stages) if table2 else None
         self.plan = DeploymentPlan(
             data_shards=shards, n_stages=n_stages, micro_batch=micro_batch,
             chunk=shards * micro_batch, stage_plan=stage_plan,
@@ -135,8 +145,8 @@ class ShardedForward:
                 bcnn.plan_layer_groups(stage_plan.bounds[s],
                                        stage_plan.bounds[s + 1],
                                        conv_fusion=plan.conv_fusion)
-                for s in range(n_stages)))
-        self._n_classes = packed.fc3_w_words.shape[0]
+                for s in range(n_stages)) if table2 else ())
+        self._n_classes = packed.n_classes
         if devices is None:
             devices = list(mesh.devices.flat)
         self.devices = tuple(devices)
@@ -149,11 +159,11 @@ class ShardedForward:
             # closed-over constants — the core/bcnn.py::split_packed
             # hot-swap contract: swap() re-binds them with zero recompiles.
             spec = sharding.batch_spec(mesh, self.plan.chunk)
-            arrays, rebuild = bcnn.split_packed(packed)
+            arrays, rebuild = packed_model.split(packed)
             self._arrays = self._replicate(arrays)
 
             def fwd(arrs, x01):
-                return bcnn.forward_packed(rebuild(arrs), x01, plan=plan)
+                return rebuild(arrs).forward(x01, plan)
 
             # check_vma=False: a pallas_call's output carries no
             # varying-axes type, and the per-shard body has no collective
@@ -180,7 +190,7 @@ class ShardedForward:
         return self.plan.data_shards
 
     @property
-    def packed(self) -> bcnn.BCNNPacked:
+    def packed(self) -> PackedModel:
         """The packed net currently being served (all shards/columns)."""
         return self._packed
 
@@ -217,13 +227,13 @@ class ShardedForward:
         return jax.device_put(arrays, NamedSharding(self.mesh, P()))
 
     # ------------------------------------------------------------ contracts
-    def swap(self, new_packed: bcnn.BCNNPacked) -> None:
+    def swap(self, new_packed: PackedModel) -> None:
         """Hot-swap the served weights (every shard / shard-column); zero
         recompiles — identical shapes reuse the compiled chunk unit
-        (checked by ``core/bcnn.py::assert_swap_compatible``)."""
+        (checked by ``core/packed_model.py::check_swap``)."""
         if self._columns is None:
             self._arrays = self._replicate(
-                bcnn.assert_swap_compatible(self._packed, new_packed))
+                packed_model.check_swap(self._packed, new_packed))
         else:
             for col in self._columns:
                 col.swap(new_packed)
@@ -239,7 +249,7 @@ class ShardedForward:
         return max(col.cache_size() for col in self._columns)
 
 
-def make_sharded_forward(packed: bcnn.BCNNPacked, mesh=None, *,
+def make_sharded_forward(packed: PackedModel, mesh=None, *,
                          data_shards: int | None = None,
                          micro_batch: int = 8, n_stages: int = 1,
                          devices=None, path: str = "mxu",

@@ -54,18 +54,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import bcnn
+from repro.core import packed_model
+from repro.core.packed_model import PackedModel
 from repro.serve.slots import SlotScheduler, SpanLog, latency_stats
 
 
 class BCNNEngine:
     """Continuous streaming engine over a one-shot image classifier.
 
-    ``forward_fn``: ``(n_slots, H, W, C) float32 → (n_slots, n_classes)``.
+    ``forward_fn``: ``(n_slots, *input_shape) input_dtype → (n_slots,
+    n_classes)``; ``input_dtype`` is the dtype images cross to the device
+    in (float32 for the Table 2 network, uint8 pixels for Bi-Real Net).
     Two kinds are accepted:
 
     * a plain shape-only function (no per-call statics) — jit'd here, once;
-      use ``BCNNEngine.from_packed`` for the paper's BCNN;
+      use ``BCNNEngine.from_packed`` for a packed network
+      (`core/packed_model.py::PackedModel`);
     * a *self-jitting* forward that manages its own compilation and exposes
       a ``cache_size()`` method — e.g. the stage-pipelined
       ``parallel/bcnn_pipeline.py::PipelinedForward``, whose per-stage jits
@@ -76,15 +80,17 @@ class BCNNEngine:
 
     def __init__(self, forward_fn: Callable, *, n_slots: int = 8,
                  input_shape: tuple[int, int, int] = (32, 32, 3),
+                 input_dtype=np.float32,
                  clock: Callable[[], float] = time.perf_counter,
                  history: int = 4096):
         self.n_slots = n_slots
         self.input_shape = tuple(input_shape)
+        self.input_dtype = np.dtype(input_dtype)
         self.sched = SlotScheduler(n_slots, clock=clock, history=history)
         # off until ``spans.enable()``; see "Tracing the engine" in
         # docs/SERVING.md for what each span covers
         self.spans = SpanLog(self.sched.clock)
-        self._x = np.zeros((n_slots, *self.input_shape), np.float32)
+        self._x = np.zeros((n_slots, *self.input_shape), self.input_dtype)
         self._self_jitting = hasattr(forward_fn, "cache_size")
         if self._self_jitting:
             # e.g. PipelinedForward: owns one jit per pipeline stage (do
@@ -106,7 +112,7 @@ class BCNNEngine:
         self._plan = None               # ExecutionPlan, for from_packed
 
     @classmethod
-    def from_packed(cls, packed: bcnn.BCNNPacked, *, n_slots: int = 8,
+    def from_packed(cls, packed: PackedModel, *, n_slots: int = 8,
                     path: str = "auto", conv_strategy: str | None = None,
                     conv_fusion: bool | None = None,
                     plan=None, autotune: bool = False,
@@ -117,11 +123,15 @@ class BCNNEngine:
                     data_micro_batch: int = 8,
                     batch_threshold: int | None = None,
                     **kw) -> "BCNNEngine":
-        """Engine over the packed deployment forward (paper Fig. 3 path).
+        """Engine over a packed network's deployment forward (paper Fig. 3
+        path): the Table 2 BCNN or Bi-Real Net, through their common seam
+        (`core/packed_model.py::PackedModel`), which also gives the engine
+        its input shape and dtype and the logits' width.
 
-        ``pipeline_stages > 1`` serves through the stage-pipelined
-        multi-device forward (``parallel/bcnn_pipeline.py``) instead of the
-        single-device ``core/bcnn.py::make_packed_forward``: the 9 layers
+        ``pipeline_stages > 1`` (Table 2 only) serves through the
+        stage-pipelined multi-device forward (``parallel/bcnn_pipeline.py``)
+        instead of the single-device
+        ``core/packed_model.py::PackedForward``: the 9 layers
         are cost-balanced onto ``pipeline_devices`` (default all local
         devices) and slot images stream through in
         ``pipeline_micro_batch``-sized granules. The serving contracts are
@@ -167,9 +177,11 @@ class BCNNEngine:
                 micro_batch=pipeline_micro_batch, devices=pipeline_devices,
                 plan=plan)
         else:
-            fwd = bcnn.make_packed_forward(packed, plan=plan)
+            fwd = packed_model.PackedForward(packed, plan)
+        kw.setdefault("input_shape", packed.input_shape)
+        kw.setdefault("input_dtype", packed.input_dtype)
         eng = cls(fwd, n_slots=n_slots, **kw)
-        eng._n_classes = packed.fc3_w_words.shape[0]
+        eng._n_classes = packed.n_classes
         eng._plan = plan
         if data_shards >= 1:
             from repro.parallel.bcnn_data_parallel import make_sharded_forward
@@ -206,8 +218,9 @@ class BCNNEngine:
 
     # ------------------------------------------------------------------ api
     def submit(self, image: np.ndarray) -> int:
-        """Enqueue one image (H, W, C in [0, 1]); returns the request id."""
-        img = np.asarray(image, np.float32)
+        """Enqueue one image (``input_shape``, cast to ``input_dtype``);
+        returns the request id."""
+        img = np.asarray(image, self.input_dtype)
         if img.shape != self.input_shape:
             raise ValueError(f"image shape {img.shape} != engine input "
                              f"shape {self.input_shape}")
@@ -255,14 +268,14 @@ class BCNNEngine:
                 results[req.rid] = logits[i]
         return results
 
-    def swap_packed(self, new_packed: bcnn.BCNNPacked
+    def swap_packed(self, new_packed: PackedModel
                     ) -> dict[int, np.ndarray]:
         """Hot-swap the served weights under live traffic, zero recompiles.
 
         The swap contract (tests/test_bcnn_swap.py):
 
         * the replacement must be shape/static-identical to the current
-          packed net (``core/bcnn.py::assert_swap_compatible``) — so every
+          packed net (``core/packed_model.py::check_swap``) — so every
           jit'd unit (slot step, pipeline stages, data-parallel chunk) hits
           its existing executable: ``step_cache_size``/``batch_cache_size``
           stay exactly where they were;
@@ -279,18 +292,18 @@ class BCNNEngine:
         if not hasattr(self._step_fn, "swap"):
             raise TypeError(
                 "this engine's forward does not support weight hot-swap; "
-                "build it with BCNNEngine.from_packed (core/bcnn.py::"
-                "PackedForward / the pipelined or data-parallel forwards)")
+                "build it with BCNNEngine.from_packed (core/packed_model.py"
+                "::PackedForward / the pipelined or data-parallel forwards)")
         # validate BEFORE draining: a rejected swap must leave the engine
         # untouched (and not silently discard the drained results)
-        bcnn.assert_swap_compatible(self._step_fn.packed, new_packed)
+        packed_model.check_swap(self._step_fn.packed, new_packed)
         if self._batch_fn is not None:
-            bcnn.assert_swap_compatible(self._batch_fn.packed, new_packed)
+            packed_model.check_swap(self._batch_fn.packed, new_packed)
         drained = self._flush()         # pre-swap weights, consistently
         self._step_fn.swap(new_packed)
         if self._batch_fn is not None:
             self._batch_fn.swap(new_packed)
-        self._n_classes = new_packed.fc3_w_words.shape[0]
+        self._n_classes = new_packed.n_classes
         return drained
 
     def run(self, max_steps: int = 100_000) -> dict[int, np.ndarray]:
@@ -324,7 +337,7 @@ class BCNNEngine:
         """
         spans = self.spans
         with spans("engine.classify_batch"):
-            images = np.asarray(images, np.float32)
+            images = np.asarray(images, self.input_dtype)
             if images.ndim != 1 + len(self.input_shape) or \
                     images.shape[1:] != self.input_shape:
                 raise ValueError(f"batch shape {images.shape} != (N, "

@@ -143,6 +143,27 @@ def test_scope_is_the_layer_in_the_framework_name_else_the_source_line():
     assert sr.scope_of({"hlo_category": "copy-start"}) == "none"
 
 
+def test_bireal_scopes_down_to_the_block_and_summed_by_stage():
+    assert sr.scope_of({"tf_op": "jit(fwd)/shard_map/bireal/stage2/block1/"
+                                 "down/dot_general"}) == \
+        "bireal/stage2/block1/down"
+    assert sr.scope_of({"tf_op": "jit(fwd)/bireal/stage1/block3/"
+                                 "jit(xnor_conv2d)/pallas_call"}) == \
+        "bireal/stage1/block3"
+    assert sr.scope_of({"tf_op": "jit(fwd)/bireal/head/reduce_sum"}) == \
+        "bireal/head"
+    events = {"device": {"/device:TPU:0": [
+        ["xnor_conv2d.3", 100, 200, "bireal/stage1/block1"],
+        ["fusion.2", 300, 100, "bireal/stage1/block2"],
+        ["dot.1", 400, 50, "bireal/stage2/block1/down"],
+        ["fusion.9", 450, 25, "bireal/head"]]},
+        "host": [["bench.window", 0, 1000]]}
+    r = sr.reduce(events)
+    assert r["stage_s"] == {"bireal/stage1": pytest.approx(300e-9),
+                            "bireal/stage2": pytest.approx(50e-9),
+                            "bireal/head": pytest.approx(25e-9)}
+
+
 # An XSpace with one TPU plane, serialized by protobuf from
 # tsl/profiler/protobuf/xplane.proto: one operation whose metadata holds a
 # string stat (its framework name), a reference stat and an integer stat,

@@ -85,6 +85,50 @@ def test_xnor_conv2d_compiles(one_chip, path, name):
     assert "tpu_custom_call" in hlo
 
 
+# the binary convs Bi-Real Net-18 adds (unfused, int32 counts out):
+# name → (C, O, H, stride)
+BIREAL_CONVS = {"stage1": (64, 64, 56, 1), "stage2_down": (64, 128, 56, 2),
+                "stage4": (512, 512, 7, 1)}
+
+
+@pytest.mark.parametrize("name", sorted(BIREAL_CONVS))
+def test_bireal_conv_compiles(one_chip, name):
+    c, o, h, stride = BIREAL_CONVS[name]
+    k = 9 * c
+
+    def conv(a, w):
+        return ops.xnor_conv2d(a, w, k=k, fh=3, fw=3, stride=stride, pad=1,
+                               path="mxu", interpret=False)
+
+    hlo = _compile(conv, _sds(one_chip, (BATCH, h, h, c), jnp.int8),
+                   _sds(one_chip, (o, k // 32), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_bireal_forward_compiles(one_chip, monkeypatch):
+    """The whole Bi-Real Net-18 forward at its published widths, 8 images
+    of 224x224 uint8 pixels, on the default plan (Pallas ``mxu``)."""
+    from repro.core import bireal, packed_model
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    geom = bireal.geometry(
+        {"input_shape": [224, 224, 3], "input_dtype": "uint8",
+         "stem": {"channels": 64, "kernel": 7, "stride": 2,
+                  "pool": {"kernel": 3, "stride": 2, "pad": 1}},
+         "stages": [[64, 4, 1], [128, 4, 2], [256, 4, 2], [512, 4, 2]],
+         "n_classes": 1000})
+    packed = bireal.fold(bireal.init(0, geom), geom)
+    plan = execution_plan.default_plan(packed, backend="tpu")
+    arrays, rebuild = packed_model.split(packed)
+
+    def step(arrs, x):
+        return rebuild(arrs).forward(x, plan)
+
+    hlo = _compile(step,
+                   tuple(_sds(one_chip, a.shape, a.dtype) for a in arrays),
+                   _sds(one_chip, (8, 224, 224, 3), jnp.uint8))
+    assert hlo.count("tpu_custom_call") >= 16
+
+
 @pytest.mark.parametrize("name", sorted(PAIRS))
 @pytest.mark.parametrize("path", ["mxu", "vpu"])
 def test_fused_pair_compiles(one_chip, path, name):

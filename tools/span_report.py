@@ -21,10 +21,12 @@ In a traced run, with the recorder on or off, ``"trace"`` holds:
 ``idle_gaps``, the longest device idle gaps, each labelled by the innermost
 program (``repro.``) or benchmark (``bench.``) host span covering its
 middle; ``idle_by_span``, all idle time summed by that label; ``layer_s``,
-device seconds per layer scope (``core/bcnn.py::group_scope``, read from
-the operation's metadata in the trace), operations outside every scope put
-down to their source line; and ``device_ops``, the longest operations with
-their scope.
+device seconds per layer scope (``core/bcnn.py::group_scope``, or for
+Bi-Real Net ``bireal/stem``, ``bireal/stage<s>/block<b>[/down]`` and
+``bireal/head``, read from the operation's metadata in the trace),
+operations outside every scope put down to their source line;
+``stage_s``, the Bi-Real scopes summed by stage; and ``device_ops``, the
+longest operations with their scope.
 
 With ``--out`` the report and a sample of the device events' metadata go
 to ``<out>/<cell>-seed-<n>-trace-<t>-rec-<r>.json``, and a traced run's
@@ -51,6 +53,7 @@ sys.path.insert(0, str(ROOT / "src"))
 sys.path.insert(0, str(ROOT))
 
 LAYER = re.compile(r"conv\d(_\d)?|fc\d")
+BIREAL = re.compile(r"stem|head|stage\d+|block\d+|down")
 WAIT_CHILD = {"engine.step": "engine.wait",
               "engine.classify_batch": "bulk.wait"}
 SAMPLE = 40         # device events kept with all their stats, to read by hand
@@ -123,10 +126,17 @@ def stall_spans(stalls, spans, intervals=None) -> list:
 # ------------------------------------------------------- the device trace
 def scope_of(stats: dict) -> str:
     """The layer scope in an operation's framework name (``tf_op``, such as
-    ``jit(fwd)/conv2/jit(xnor_conv2d)/reduce_sum``); outside every scope,
-    the source line it came from (``source:<file>:<line>``); else
-    "none"."""
-    for part in str(stats.get("tf_op", "")).split("/"):
+    ``jit(fwd)/conv2/jit(xnor_conv2d)/reduce_sum``, or
+    ``jit(fwd)/bireal/stage2/block1/down/dot_general``, whose scope is
+    ``bireal/stage2/block1/down``); outside every scope, the source line it
+    came from (``source:<file>:<line>``); else "none"."""
+    parts = str(stats.get("tf_op", "")).split("/")
+    if "bireal" in parts:
+        i = j = parts.index("bireal") + 1
+        while j < len(parts) and BIREAL.fullmatch(parts[j]):
+            j += 1
+        return "/".join(parts[i - 1:j])
+    for part in parts:
         if LAYER.fullmatch(part):
             return part
     src = stats.get("source")
@@ -286,6 +296,11 @@ def reduce(events: dict, top: int = 10) -> dict:
     by_span: dict[str, float] = {}
     for name, d in labelled:
         by_span[name] = by_span.get(name, 0.0) + d / n
+    stage_ns: dict[str, int] = {}
+    for scope, ns in layer_ns.items():
+        if scope.startswith("bireal/"):
+            stage = "/".join(scope.split("/")[:2])
+            stage_ns[stage] = stage_ns.get(stage, 0) + ns
     return {
         "window_s": (t1 - t0) / 1e9,
         "idle_gaps": [list(g) for g in
@@ -293,6 +308,8 @@ def reduce(events: dict, top: int = 10) -> dict:
         "idle_by_span": dict(sorted(by_span.items(), key=lambda kv: -kv[1])),
         "layer_s": {k: v / n / 1e9 for k, v in
                     sorted(layer_ns.items(), key=lambda kv: -kv[1])},
+        "stage_s": {k: v / n / 1e9 for k, v in
+                    sorted(stage_ns.items(), key=lambda kv: -kv[1])},
         "device_ops": [[k[0], k[1], v / n / 1e9] for k, v in
                        sorted(op_ns.items(), key=lambda kv: -kv[1])[:top]],
     }
