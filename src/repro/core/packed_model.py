@@ -8,8 +8,9 @@ values (reduction lengths, filter sizes, strides). The serving paths —
 ``classify_batch``) — need of it only what ``PackedModel`` states, plus the
 two functions that every packed pytree shares: ``split`` (arrays out as jit
 arguments, statics closed over) and ``check_swap`` (may ``new`` replace
-``old`` under a compiled forward). The Table 2 BCNN
-(``core/bcnn.py::BCNNPacked``) and Bi-Real Net
+``old`` under a compiled forward). ``to_wire`` and ``from_wire`` give the
+form a bulk batch crosses to the device in (``ShardedForward``). The
+Table 2 BCNN (``core/bcnn.py::BCNNPacked``) and Bi-Real Net
 (``core/bireal.py::BiRealPacked``) implement it.
 """
 from __future__ import annotations
@@ -19,6 +20,7 @@ from typing import Protocol
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 
 class PackedModel(Protocol):
@@ -101,6 +103,31 @@ def check_swap(old, new) -> tuple:
             raise ValueError(f"leaf {i}: static mismatch {a!r} != {b!r} "
                              f"(k/filter-size/eps must be identical)")
     return tuple(l for l in ln if _is_weight_array(l))
+
+
+def to_wire(images):
+    """(N, *input_shape) images → their wire form, the 2-D array a bulk
+    batch crosses to the device in: each image one row of 32-bit words
+    (``images.reshape(N, -1)`` viewed as uint32), or, where a row's bytes
+    are not a whole number of words, one row in the images' own dtype.
+
+    A 4-D image batch is given a batch-minor layout on the device, which
+    the host fills one element at a time; a 2-D array of 32-bit words is
+    laid out row-major in tiles of whole rows, which the host fills by tile
+    copies. On a C-contiguous NumPy batch the result is a view, no copy;
+    on a device array (traced or not) it is the same relayout, done there.
+    """
+    flat = images.reshape(images.shape[0], -1)
+    if flat.dtype.itemsize * flat.shape[1] % 4:
+        return flat
+    return flat.view(np.uint32)
+
+
+def from_wire(x: jnp.ndarray, input_shape, input_dtype) -> jnp.ndarray:
+    """The wire form back to (n, *input_shape) ``input_dtype`` images, the
+    same bytes; traced, as the first operation of a forward."""
+    return lax.bitcast_convert_type(x, input_dtype).reshape(
+        x.shape[0], *input_shape)
 
 
 class PackedForward:

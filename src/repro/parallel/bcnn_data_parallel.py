@@ -48,6 +48,7 @@ from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
@@ -91,7 +92,8 @@ class DeploymentPlan(NamedTuple):
 
 
 class ShardedForward:
-    """Callable: (N, *input_shape) inputs → (N, n_classes) logits,
+    """Callable: (N, *input_shape) inputs, or their (N, row) wire form
+    (``core/packed_model.py::to_wire``) → (N, n_classes) logits,
     batch-sharded, for any `core/packed_model.py::PackedModel`.
 
     Built by ``make_sharded_forward``. Accepts ANY batch size N (including
@@ -103,10 +105,16 @@ class ShardedForward:
     (``core/bcnn.py::forward_packed``, ``core/bireal.py::forward_packed``).
 
     With ``n_stages == 1`` the chunk function is one jit'd ``shard_map``
-    over the mesh's data axes. With ``n_stages > 1`` (the Table 2 network
+    over the mesh's data axes (``chunk_unit``), and the batch crosses to
+    the device in its wire form, told apart by rank: a 2-D array is the
+    wire form and passes straight on; host images are viewed as it on the
+    host, for free; device-resident images are relaid by one small jit on
+    the device. ``input_counts`` counts the calls by route (``"wire"``,
+    ``"device_relayout"``). With ``n_stages > 1`` (the Table 2 network
     only) each shard column is a
     ``parallel/bcnn_pipeline.py::PipelinedForward`` over its own stage
-    devices; the chunk is split host-side and the columns run
+    devices, fed (n, *input_shape) images (a wire batch is restored on the
+    device first); the chunk is split host-side and the columns run
     concurrently via async dispatch.
 
     ``cache_size()`` is the one-compilation-per-plan contract (the chunk
@@ -153,24 +161,13 @@ class ShardedForward:
         self._packed = packed
         if n_stages == 1:
             # pure data parallelism: ONE shard_map'd jit of the whole
-            # packed forward; the batch spec comes from the same helper
-            # the LM input pipeline uses (P over the mesh's DP axes). The
-            # weight arrays ride as a replicated (P()) argument rather than
-            # closed-over constants — the core/bcnn.py::split_packed
-            # hot-swap contract: swap() re-binds them with zero recompiles.
-            spec = sharding.batch_spec(mesh, self.plan.chunk)
-            arrays, rebuild = packed_model.split(packed)
+            # packed forward, fed the wire form
+            arrays, self._chunk_fn = chunk_unit(packed, mesh, plan,
+                                                self.plan.chunk)
             self._arrays = self._replicate(arrays)
-
-            def fwd(arrs, x01):
-                return rebuild(arrs).forward(x01, plan)
-
-            # check_vma=False: a pallas_call's output carries no
-            # varying-axes type, and the per-shard body has no collective
-            # that would need one
-            self._chunk_fn = jax.jit(jax.shard_map(
-                fwd, mesh=mesh, in_specs=(P(), spec), out_specs=spec,
-                check_vma=False))
+            # to_wire on the device, for a device-resident image batch
+            self._relayout = jax.jit(lambda x: packed_model.to_wire(
+                x.astype(packed.input_dtype)))
             self._columns = None
         else:
             # 2-D plan: shard column s pipelines the 9 layers over its own
@@ -184,6 +181,9 @@ class ShardedForward:
                      for j in range(n_stages)],
                     micro_batch, plan=plan)
                 for s in range(shards))
+            self._unwire = jax.jit(lambda w: packed_model.from_wire(
+                w, packed.input_shape, packed.input_dtype))
+        self.input_counts = {"wire": 0, "device_relayout": 0}
 
     @property
     def data_shards(self) -> int:
@@ -194,13 +194,17 @@ class ShardedForward:
         """The packed net currently being served (all shards/columns)."""
         return self._packed
 
-    def __call__(self, x01: jnp.ndarray) -> jnp.ndarray:
-        n = x01.shape[0]
+    def __call__(self, x) -> jnp.ndarray:
+        n = x.shape[0]
         if n == 0:          # drop-in contract: empty batch → empty logits
             return jnp.zeros((0, self._n_classes), jnp.float32)
+        if self._columns is None:
+            x = self._to_wire(x)
+        elif x.ndim == 2:   # the stage columns take (n, *input_shape)
+            x = self._unwire(x)
         chunk = self.plan.chunk
         n_chunks = -(-n // chunk)
-        x = pad_rows(jnp.asarray(x01), n_chunks * chunk)    # ragged tail
+        x = pad_rows(jnp.asarray(x), n_chunks * chunk)      # ragged tail
         outs = []
         for c in range(n_chunks):
             xc = x[c * chunk:(c + 1) * chunk]
@@ -218,6 +222,18 @@ class ShardedForward:
                      for s, col in enumerate(self._columns)]))
         logits = jnp.concatenate(outs) if len(outs) > 1 else outs[0]
         return logits[:n]
+
+    def _to_wire(self, x):
+        """The batch in the chunk unit's wire form, counted in
+        ``input_counts`` by the route it took (the class docstring)."""
+        if x.ndim == 2:
+            self.input_counts["wire"] += 1
+            return x
+        if isinstance(x, jax.Array):
+            self.input_counts["device_relayout"] += 1
+            return self._relayout(x)
+        self.input_counts["wire"] += 1
+        return packed_model.to_wire(np.asarray(x, self._packed.input_dtype))
 
     def _replicate(self, arrays) -> tuple:
         """Replicate the weight arrays onto the whole mesh once (they ride
@@ -247,6 +263,30 @@ class ShardedForward:
         if self._columns is None:
             return int(self._chunk_fn._cache_size())
         return max(col.cache_size() for col in self._columns)
+
+
+def chunk_unit(packed: PackedModel, mesh, plan, chunk: int):
+    """The jit'd unit of a data-parallel forward: ``(weight arrays, wire
+    chunk) → (chunk, n_classes)`` logits, one ``shard_map`` over the mesh's
+    data axes. The chunk arrives in its wire form
+    (``core/packed_model.py::to_wire``), which the unit restores to
+    ``(n, *input_shape)`` images as its first operation, on each shard.
+    Returns ``(weight arrays, unit)``; the arrays ride as a replicated
+    (``P()``) argument, not closed-over constants, so a swap re-binds them
+    with zero recompiles."""
+    spec = sharding.batch_spec(mesh, chunk)
+    arrays, rebuild = packed_model.split(packed)
+    shape, dtype = packed.input_shape, packed.input_dtype
+
+    def fwd(arrs, w):
+        return rebuild(arrs).forward(
+            packed_model.from_wire(w, shape, dtype), plan)
+
+    # check_vma=False: a pallas_call's output carries no varying-axes type,
+    # and the per-shard body has no collective that would need one
+    return arrays, jax.jit(jax.shard_map(
+        fwd, mesh=mesh, in_specs=(P(), spec), out_specs=spec,
+        check_vma=False))
 
 
 def make_sharded_forward(packed: PackedModel, mesh=None, *,

@@ -323,7 +323,9 @@ class BCNNEngine:
         ``from_packed(data_shards=...)``) bypasses the slots and runs
         through the batch-sharded data-parallel forward
         (``parallel/bcnn_data_parallel.py``) — one compile per plan, any
-        batch size. Smaller batches stream through the slot scheduler
+        batch size; the batch crosses to the device in its row-major wire
+        form (``core/packed_model.py::to_wire``), which the forward restores
+        on the device. Smaller batches stream through the slot scheduler
         exactly like individually submitted requests. Both routes produce
         bit-identical logits.
 
@@ -352,8 +354,8 @@ class BCNNEngine:
                 return np.zeros((0, self._n_classes or 0), np.float32)
             if self._batch_fn is not None and \
                     len(images) >= self._batch_threshold:
-                with spans("bulk.put"):
-                    x = jnp.asarray(images)
+                with spans("bulk.put"):     # row-major: no host transpose
+                    x = jnp.asarray(packed_model.to_wire(images))
                 with spans("bulk.dispatch"):
                     y = self._batch_fn(x)
                 with spans("bulk.wait"):
@@ -400,6 +402,15 @@ class BCNNEngine:
         first use, then exactly 1 per (shards, stages, micro-batch) plan
         whatever batch sizes ``classify_batch`` has seen."""
         return 0 if self._batch_fn is None else self._batch_fn.cache_size()
+
+    @property
+    def batch_input_counts(self) -> dict:
+        """Calls of the bulk forward by the route their batch took
+        (``ShardedForward.input_counts``: ``"wire"``, ``"device_relayout"``);
+        ``classify_batch`` sends the wire form, so its calls count as
+        ``"wire"``. Empty when the bulk path is disabled."""
+        return {} if self._batch_fn is None else dict(
+            self._batch_fn.input_counts)
 
     @property
     def step_cache_size(self) -> int:

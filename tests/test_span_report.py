@@ -228,5 +228,12 @@ def test_a_traced_cpu_run_reads_the_program_spans(root, tmp_path, workload,
     assert rep["top"] == top and rep["n"] >= 1 and rep["dropped"] == 0
     assert rep["host_ms"] > 0 and rep["wait_ms"] > 0
     assert len(rep["stalls"]) >= 1
+    # the online engine has no bulk path; every offline call's batch
+    # crossed in the wire form
+    counts = line["bulk_input_counts"]
+    if top == "engine.step":
+        assert counts == {}
+    else:
+        assert counts["wire"] >= 1 and counts["device_relayout"] == 0
     assert "trace" not in line                  # no device plane on a CPU
     assert len(list(tmp_path.glob("*.json"))) == 1

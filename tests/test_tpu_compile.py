@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import bcnn, execution_plan
+from repro.core import bcnn, bireal, execution_plan
 from repro.kernels import ops
 
 BATCH = 4               # the serving engine's slot count (SERVE_N_SLOTS)
@@ -105,18 +105,21 @@ def test_bireal_conv_compiles(one_chip, name):
     assert "tpu_custom_call" in hlo
 
 
+# Bi-Real Net-18 at its published widths
+BIREAL18 = bireal.geometry(
+    {"input_shape": [224, 224, 3], "input_dtype": "uint8",
+     "stem": {"channels": 64, "kernel": 7, "stride": 2,
+              "pool": {"kernel": 3, "stride": 2, "pad": 1}},
+     "stages": [[64, 4, 1], [128, 4, 2], [256, 4, 2], [512, 4, 2]],
+     "n_classes": 1000})
+
+
 def test_bireal_forward_compiles(one_chip, monkeypatch):
     """The whole Bi-Real Net-18 forward at its published widths, 8 images
     of 224x224 uint8 pixels, on the default plan (Pallas ``mxu``)."""
-    from repro.core import bireal, packed_model
+    from repro.core import packed_model
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    geom = bireal.geometry(
-        {"input_shape": [224, 224, 3], "input_dtype": "uint8",
-         "stem": {"channels": 64, "kernel": 7, "stride": 2,
-                  "pool": {"kernel": 3, "stride": 2, "pad": 1}},
-         "stages": [[64, 4, 1], [128, 4, 2], [256, 4, 2], [512, 4, 2]],
-         "n_classes": 1000})
-    packed = bireal.fold(bireal.init(0, geom), geom)
+    packed = bireal.fold(bireal.init(0, BIREAL18), BIREAL18)
     plan = execution_plan.default_plan(packed, backend="tpu")
     arrays, rebuild = packed_model.split(packed)
 
@@ -126,6 +129,34 @@ def test_bireal_forward_compiles(one_chip, monkeypatch):
     hlo = _compile(step,
                    tuple(_sds(one_chip, a.shape, a.dtype) for a in arrays),
                    _sds(one_chip, (8, 224, 224, 3), jnp.uint8))
+    assert hlo.count("tpu_custom_call") >= 16
+
+
+def test_bireal_bulk_chunk_takes_the_wire_form(one_chip, topo, monkeypatch):
+    """The bulk path's chunk unit (``ShardedForward``'s) for Bi-Real Net-18
+    at 128 images: its input parameter is the row-major wire form, laid
+    out in tiles of whole rows, so the host fills it by tile copies and
+    transposes no element; a 4-D image batch would be given a batch-minor
+    layout here. The forward inside is whole: every binary conv's
+    kernel."""
+    from repro.launch.mesh import make_data_mesh
+    from repro.parallel.bcnn_data_parallel import chunk_unit
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    packed = bireal.fold(bireal.init(0, BIREAL18), BIREAL18)
+    plan = execution_plan.default_plan(packed, backend="tpu")
+    mesh = make_data_mesh(1, devices=topo.devices[:1])
+    arrays, unit = chunk_unit(packed, mesh, plan, 128)
+    rep = NamedSharding(mesh, P())
+    hlo = unit.lower(
+        tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep)
+              for a in arrays),
+        jax.ShapeDtypeStruct((128, 224 * 224 * 3 // 4), jnp.uint32,
+                             sharding=NamedSharding(mesh, P("data")))
+    ).compile().as_text()
+    params = hlo.split("entry_computation_layout={(", 1)[1].split(")->")[0]
+    wire = params.rsplit(", ", 1)[1]
+    assert wire.startswith("u32[128,37632]{1,0:T(8,128)"), wire
     assert hlo.count("tpu_custom_call") >= 16
 
 

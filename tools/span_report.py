@@ -8,7 +8,9 @@ does (``bench/harness.py::run_cell``), with the BCNN engine's span log
 (``serve/slots.py::SpanLog``) turned on over the window when
 ``--recorder 1``. It prints one JSON object as the last line: the run's
 result as ``bench/run.py`` prints it, ``engine_step_ms`` (the harness's
-reading of its own step span), and under ``"spans"``:
+reading of its own step span), ``bulk_input_counts`` (the bulk forward's
+calls by the route their batch took, ``BCNNEngine.batch_input_counts``;
+empty for an engine with no bulk path), and under ``"spans"``:
 
 * ``host_ms`` / ``wait_ms``: the medians, over the engine's ``engine.step``
   (or ``engine.classify_batch``) spans that ended in the undisturbed
@@ -369,7 +371,8 @@ def report(workload: str, seed: int, seconds: float, trace: bool,
     engine = hooks.engine
     line = {"result": result,
             "engine_step_ms": harness.metric_reader(
-                root, "engine_step_ms.online").read(run)}
+                root, "engine_step_ms.online").read(run),
+            "bulk_input_counts": engine.batch_input_counts}
     if recorder:
         spans = window_spans(engine.spans.read(), hooks.t0, run.steady_end)
         top = ("engine.step" if hooks.sched.loop == "open"
