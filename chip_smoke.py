@@ -36,6 +36,7 @@ no result line is printed. One process, no children.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -138,7 +139,7 @@ def one_chip(seed: int) -> None:
     # the references: the XLA path on this chip, at the batch sizes the
     # serving paths run (slot-sized steps, one offline chunk)
     ref_fwd = bcnn.make_packed_forward(
-        packed, plan=execution_plan.build_plan(packed, path="xla"))
+        packed, plan=dataclasses.replace(plan, path="xla"))
     ref_slots = np.concatenate([np.asarray(ref_fwd(x[i:i + n_slots]))
                                 for i in range(0, N_OFFLINE, n_slots)])
     ref_bulk = np.asarray(ref_fwd(x))

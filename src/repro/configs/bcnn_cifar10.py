@@ -1,33 +1,21 @@
 """The paper's own model: the 9-layer CIFAR-10 BCNN of Table 2.
 
 Not one of the 10 assigned LM architectures — this is the reproduction
-target itself (core/bcnn.py builds it; benchmarks/table3|table5|fig7 and
-examples/train_bcnn_cifar10.py consume this module).
+target itself (core/bcnn.py builds it; the launchers and
+examples/train_bcnn_cifar10.py consume this module). Kernel choices are not
+configured here: they come from `core/execution_plan.py::default_plan` or
+the tuner (`kernels/autotune.py`) as one ``ExecutionPlan``.
 """
 from __future__ import annotations
 
 from repro.core.bcnn import CONV_SPECS, FC_SPECS          # noqa: F401
 from repro.core.throughput import (BCNN_CONV_LAYERS,      # noqa: F401
                                    BCNN_FC_SPECS, FREQ_HZ, PAPER_FPS,
-                                   PAPER_POWER_W, PAPER_TABLE3, PAPER_TOPS)
+                                   PAPER_TABLE3, PAPER_TOPS)
 
 NAME = "bcnn-cifar10"
 INPUT_SHAPE = (32, 32, 3)          # CIFAR-10 RGB
 N_CLASSES = 10
-
-# Binary-conv dataflow for the deployment path (core/bconv.py):
-# "direct" = fused im2col-free Pallas kernel (paper Fig. 5/6 dataflow),
-# "im2col" = patch-matmul lowering, "auto" = direct when C % 32 == 0.
-# All BCNN conv layers have 32-aligned channels, so "auto" → direct.
-from repro.core.bconv import DEFAULT_CONV_STRATEGY as CONV_STRATEGY  # noqa: E402,F401
-
-# Cross-layer conv fusion (kernels/xnor_conv_fused.py, planned by
-# core/bcnn.py::plan_layer_groups): fuse the Table 2 same-resolution conv
-# pairs (CONV-3/4, CONV-5/6) into one megakernel whose intermediate bit map
-# never touches HBM. Bit-exact with the unfused fold; opt-in by default —
-# flip with `launch/serve_bcnn.py --conv-fusion` or the per-forward
-# ``conv_fusion=`` argument.
-from repro.core.bconv import DEFAULT_CONV_FUSION as CONV_FUSION  # noqa: E402,F401
 
 # Training defaults (train/bcnn_train.py, launch/train_bcnn.py): the
 # Courbariaux/Bengio recipe's CPU-scale operating point — ~2 min wall for
@@ -38,15 +26,9 @@ TRAIN_BATCH = 64
 TRAIN_LR = 2e-3
 TRAIN_CKPT_EVERY = 50
 
-# Paper Fig. 7 benchmark batch sizes (FPGA vs GPU sweep)
-FIG7_BATCH_SIZES = (16, 32, 64, 128, 256, 512)
-
-# Streaming-service defaults (serve/bcnn_engine.py, launch/serve_bcnn.py,
-# benchmarks/fig7.py --online): slot count for the continuously-stepped
-# engine, and the offered-load fractions (of measured single-engine
-# capacity) swept by the online benchmark's Poisson arrival process.
+# Streaming-service default (serve/bcnn_engine.py, launch/serve_bcnn.py):
+# slot count for the continuously-stepped engine.
 SERVE_N_SLOTS = 4
-FIG7_ONLINE_LOAD_FRACS = (0.25, 0.6, 0.9)
 
 # Fleet serving (serve/router.py, launch/serve_bcnn.py --replicas): the
 # async request router over N replicated engines. ROUTER_REPLICAS = 1
@@ -55,15 +37,11 @@ FIG7_ONLINE_LOAD_FRACS = (0.25, 0.6, 0.9)
 # shed with a typed RouterOverload); ONLINE_DEADLINE_S is the latency SLO
 # of the "online" traffic class (the "bulk" class is best-effort);
 # PRIORITY_MIX is the default offered-traffic composition of the mixed
-# Poisson driver ("class=weight,..."). The `benchmarks/fig7.py --router`
-# sweep drives FIG7_ROUTER_REPLICAS replicas at FIG7_ROUTER_LOAD_FRACS
-# fractions of measured fleet capacity.
+# Poisson driver ("class=weight,...").
 ROUTER_REPLICAS = 1
 ROUTER_MAX_QUEUE = 256
 ONLINE_DEADLINE_S = 0.5
 PRIORITY_MIX = "online=3,bulk=1"
-FIG7_ROUTER_REPLICAS = 2
-FIG7_ROUTER_LOAD_FRACS = (0.25, 0.6, 0.9)
 
 # Elastic fleet autoscaling + mixed-traffic co-scheduling
 # (serve/autoscale.py, serve/router.py, launch/serve_bcnn.py --autoscale):
@@ -72,8 +50,7 @@ FIG7_ROUTER_LOAD_FRACS = (0.25, 0.6, 0.9)
 # down < up/2 — the oscillation-free invariant), while bulk batches are
 # co-scheduled as BULK_CHUNK-image micro-chunks through the same
 # priority/EDF scheduler with ONLINE_RESERVE per-replica dispatch slots
-# bulk may never occupy. `benchmarks/fig7.py --autoscale` sweeps a
-# low→burst→idle load step against these defaults.
+# bulk may never occupy.
 AUTOSCALE_MIN_REPLICAS = 1
 AUTOSCALE_MAX_REPLICAS = 4
 AUTOSCALE_UP_WATERMARK = 2.0
@@ -87,11 +64,9 @@ BULK_CHUNK = 2
 # Stage-pipelined deployment forward (parallel/bcnn_pipeline.py): number of
 # cost-balanced pipeline stages the packed 9-layer forward is cut into
 # (1 = single-device make_packed_forward, the default) and the micro-batch
-# granule streamed through them. Stage counts swept by
-# `benchmarks/fig7.py --pipeline`.
+# granule streamed through them.
 PIPELINE_STAGES = 1
 PIPELINE_MICRO_BATCH = 1
-FIG7_PIPELINE_STAGE_COUNTS = (1, 2, 3)
 
 # Data-parallel batch serving (parallel/bcnn_data_parallel.py): the
 # paper's large-batch Fig. 7 scenario. DATA_SHARDS replicates the packed
@@ -99,24 +74,6 @@ FIG7_PIPELINE_STAGE_COUNTS = (1, 2, 3)
 # (0 = bulk path disabled — slot streaming only); DATA_MICRO_BATCH is the
 # per-shard granule, so DATA_SHARDS × DATA_MICRO_BATCH is the one jit'd
 # chunk shape (and the default BCNNEngine.classify_batch routing
-# threshold). The `benchmarks/fig7.py --offline` sweep crosses
-# FIG7_OFFLINE_BATCH_SIZES with FIG7_DATA_SHARD_COUNTS on (simulated)
-# devices.
+# threshold).
 DATA_SHARDS = 0
 DATA_MICRO_BATCH = 8
-FIG7_OFFLINE_BATCH_SIZES = (4, 8, 16, 32, 64)
-FIG7_DATA_SHARD_COUNTS = (1, 2)
-
-# Paper Fig. 7 reported numbers (digitized): throughput in FPS and
-# energy-efficiency ratios used by benchmarks/fig7.py for validation.
-PAPER_FPGA_FPS = 6218              # batch-size-invariant (the paper's claim)
-PAPER_GPU_XNOR_FPS_B16 = 749       # 6218 / 8.3  (paper: 8.3× at batch 16)
-PAPER_GPU_XNOR_FPS_B512 = 6218     # "on a par" at batch 512
-PAPER_FPGA_W = 8.2
-# GPU power implied by the paper's own published ratios (it does not print
-# the wattage): 75× eff @ b16 with 8.3× speedup → P = 749·75·8.2/6218 ≈ 74 W;
-# the b512 endpoint gives ≈ 78 W. We use the midpoint.
-PAPER_GPU_W = 76.0
-# 75× energy efficiency at batch 16; 9.5× at batch 512 (paper §6.3)
-PAPER_EFF_RATIO_B16 = 75.0
-PAPER_EFF_RATIO_B512 = 9.5

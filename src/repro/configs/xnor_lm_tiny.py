@@ -4,7 +4,7 @@ Not one of the published-architecture table entries (``ARCH_MODULES``):
 this is the repo's own binary workload — `models/xnor_lm.py` — registered
 under ``BINARY_LM_MODULES`` so `launch/serve.py --arch xnor-lm-tiny`
 resolves here. CONFIG is a small-but-real shape; SMOKE_CONFIG is the CPU
-test/CI shape (also what the fig7 LM benchmark section uses).
+test/CI shape.
 """
 from repro.models.xnor_lm import XnorLMConfig
 

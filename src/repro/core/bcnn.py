@@ -205,10 +205,8 @@ def fold_model(params: BCNNParams) -> BCNNPacked:
 N_LAYERS = 9  # CONV-1..6 (indices 0..5) + FC-1..3 (indices 6..8)
 
 
-def apply_packed_layer(packed: BCNNPacked, idx: int, h: jnp.ndarray, *,
-                       path: str = "mxu",
-                       conv_strategy: str | None = None,
-                       plan=None) -> jnp.ndarray:
+def apply_packed_layer(packed: BCNNPacked, idx: int, h: jnp.ndarray,
+                       plan) -> jnp.ndarray:
     """Apply ONE layer of the packed deployment forward (paper Fig. 3).
 
     ``h`` is the layer's input in its *natural* inter-layer form, and the
@@ -227,22 +225,18 @@ def apply_packed_layer(packed: BCNNPacked, idx: int, h: jnp.ndarray, *,
     (``parallel/bcnn_pipeline.py``) partitions; ``forward_packed`` is the
     sequential fold of all ``N_LAYERS`` of them.
 
-    ``plan`` — an `core/execution_plan.py::ExecutionPlan`; when given it
-    supplies the kernel path and the per-layer resolved conv strategy, and
-    the bare ``path=``/``conv_strategy=`` kwargs are ignored (they remain
-    as deprecated shims for one release).
+    ``plan`` — an `core/execution_plan.py::ExecutionPlan` — supplies the
+    kernel path and the layer's resolved conv strategy.
     """
     from repro.kernels import ops
-    if plan is not None:
-        path = plan.path
-        conv_strategy = plan.strategy_for(idx)
+    path = plan.path
     if idx == 0:
         # layer 1: fp conv (eq. 7) → NormBinarize → {0,1} bits
         return bitpack.encode_pm1(bconv.fpconv_apply(packed.conv1, h))
     if 1 <= idx <= 5:
         return bconv.apply_packed(packed.convs[idx - 1], h,
                                   maxpool=CONV_SPECS[idx][2], path=path,
-                                  strategy=conv_strategy)
+                                  strategy=plan.strategy_for(idx))
     if idx in (6, 7):
         if idx == 6:                                    # conv→fc flatten+pack
             h = bitpack.pack_bits(h.reshape(h.shape[0], -1))      # (N, 256)
@@ -257,31 +251,29 @@ def apply_packed_layer(packed: BCNNPacked, idx: int, h: jnp.ndarray, *,
 
 
 def plan_layer_groups(start: int = 0, stop: int = N_LAYERS, *,
-                      conv_fusion: bool | None = None
-                      ) -> tuple[tuple[int, ...], ...]:
+                      conv_fusion: bool) -> tuple[tuple[int, ...], ...]:
     """Partition layers [start, stop) into fused execution groups.
 
-    With ``conv_fusion`` off (None → ``bconv.DEFAULT_CONV_FUSION``) every
-    group is a singleton — the classic one-layer-at-a-time fold. With it on,
-    consecutive binary conv layers running at the SAME spatial resolution —
-    the first member has no trailing max-pool — pair into one fused
-    megakernel call (``kernels/xnor_conv_fused.py``). Table 2 yields exactly
-    the boundary-dominated pairs: CONV-3/CONV-4 (16×16 maps, eliminating the
-    16·16·256 bit-map boundary) and CONV-5/CONV-6 (8×8 maps, eliminating
-    8·8·512). Max-pool boundaries — where the resolution drops — are never
-    fused across (a pooling layer can only *end* a group, its pool running
-    as the kernel epilogue), and a group never crosses [start, stop): the
-    stage-cut contract of ``parallel/bcnn_pipeline.py::PipelinedForward``.
+    ``conv_fusion`` is the plan's (`core/execution_plan.py::ExecutionPlan`).
+    With it off every group is a singleton — the classic one-layer-at-a-time
+    fold. With it on, consecutive binary conv layers running at the SAME
+    spatial resolution — the first member has no trailing max-pool — pair
+    into one fused megakernel call (``kernels/xnor_conv_fused.py``). Table 2
+    yields exactly the boundary-dominated pairs: CONV-3/CONV-4 (16×16 maps,
+    eliminating the 16·16·256 bit-map boundary) and CONV-5/CONV-6 (8×8 maps,
+    eliminating 8·8·512). Max-pool boundaries — where the resolution drops —
+    are never fused across (a pooling layer can only *end* a group, its pool
+    running as the kernel epilogue), and a group never crosses [start,
+    stop): the stage-cut contract of
+    ``parallel/bcnn_pipeline.py::PipelinedForward``.
 
     Returns a tuple of index tuples that partitions ``range(start, stop)``
     in order; every group is a singleton or a fusible (i, i+1) pair.
     """
-    fusion = (bconv.DEFAULT_CONV_FUSION if conv_fusion is None
-              else bool(conv_fusion))
     groups = []
     i = start
     while i < stop:
-        if (fusion and 1 <= i < 5 and i + 1 < stop
+        if (conv_fusion and 1 <= i < 5 and i + 1 < stop
                 and not CONV_SPECS[i][2]):
             groups.append((i, i + 1))
             i += 2
@@ -300,18 +292,16 @@ def group_scope(group: tuple[int, ...]) -> str:
 
 
 def apply_packed_group(packed: BCNNPacked, group: tuple[int, ...],
-                       h: jnp.ndarray, *, path: str = "mxu",
-                       conv_strategy: str | None = None,
-                       plan=None) -> jnp.ndarray:
+                       h: jnp.ndarray, plan) -> jnp.ndarray:
     """Apply ONE ``plan_layer_groups`` group of the packed forward.
 
     Singleton groups defer to ``apply_packed_layer``; (i, i+1) pairs run the
     fused megakernel via ``bconv.apply_packed_pair`` — bit-exact with the
     two-layer sequential fold, but the intermediate bit map never leaves
-    VMEM. ``conv_strategy`` only shapes unfused layers (the fused kernel is
-    its own dataflow). With a ``plan``
-    (`core/execution_plan.py::ExecutionPlan`) the path, per-layer strategy,
-    and the fused pair's (th, tw) output tile all come from the plan.
+    VMEM. The path, the per-layer strategy (which only shapes unfused
+    layers: the fused kernel is its own dataflow) and the fused pair's
+    (th, tw) output tile all come from the ``plan``
+    (`core/execution_plan.py::ExecutionPlan`).
 
     The group's operations run under the named scope ``group_scope(group)``,
     which a device trace carries in each operation's metadata; the
@@ -319,60 +309,37 @@ def apply_packed_group(packed: BCNNPacked, group: tuple[int, ...],
     """
     with jax.named_scope(group_scope(group)):
         if len(group) == 1:
-            return apply_packed_layer(packed, group[0], h, path=path,
-                                      conv_strategy=conv_strategy, plan=plan)
+            return apply_packed_layer(packed, group[0], h, plan)
         i, j = group
         if j != i + 1 or not 1 <= i < j <= 5:
             raise ValueError(f"not a fusible binary-conv pair: {group}")
-        tiles = None
-        if plan is not None:
-            path = plan.path
-            tiles = plan.tiles_for(i)
         return bconv.apply_packed_pair(packed.convs[i - 1],
                                        packed.convs[j - 1], h,
-                                       maxpool_b=CONV_SPECS[j][2], path=path,
-                                       tiles=tiles)
+                                       maxpool_b=CONV_SPECS[j][2],
+                                       path=plan.path,
+                                       tiles=plan.tiles_for(i))
 
 
 def forward_packed(packed: BCNNPacked, x01: jnp.ndarray,
-                   path: str = "mxu",
-                   conv_strategy: str | None = None,
-                   conv_fusion: bool | None = None,
                    plan=None) -> jnp.ndarray:
     """Deployment forward: bit feature maps all the way (paper Fig. 3).
 
     All kernel choices live in ONE ``plan``
-    (`core/execution_plan.py::ExecutionPlan`); when None, the deprecated
-    ``path``/``conv_strategy``/``conv_fusion`` kwargs are resolved into a
-    plan via `core/execution_plan.py::build_plan` — the historical rules,
-    applied once up front, so legacy call sites compute bit-exactly what
-    they always did. Not jit'd at the top level: the packed artifacts carry
-    static ints (k) that must stay Python values; each XNOR kernel call is
-    jit'd internally.
+    (`core/execution_plan.py::ExecutionPlan`); None means
+    `core/execution_plan.py::default_plan`. Not jit'd at the top level: the
+    packed artifacts carry static ints (k) that must stay Python values;
+    each XNOR kernel call is jit'd internally.
     """
     if plan is None:
         from repro.core import execution_plan
-        plan = execution_plan.build_plan(
-            packed, path=path, conv_strategy=conv_strategy,
-            conv_fusion=conv_fusion, input_hw=x01.shape[1:3])
+        plan = execution_plan.default_plan(packed)
     h = x01
     for group in plan_layer_groups(conv_fusion=plan.conv_fusion):
         h = apply_packed_group(packed, group, h, plan=plan)
     return h
 
 
-# ---------------------------------------------------------------------------
-# Weight hot-swap plumbing: arrays ride as jit ARGUMENTS, statics stay closed
-# (shared by every packed model: core/packed_model.py)
-# ---------------------------------------------------------------------------
-
-split_packed = packed_model.split
-assert_swap_compatible = packed_model.check_swap
-
-
-def make_packed_forward(packed: BCNNPacked, *, path: str = "mxu",
-                        conv_strategy: str | None = None,
-                        conv_fusion: bool | None = None,
+def make_packed_forward(packed: BCNNPacked, *,
                         plan=None) -> packed_model.PackedForward:
     """Close the packed statics over ``forward_packed`` → a
     `core/packed_model.py::PackedForward`.
@@ -382,18 +349,15 @@ def make_packed_forward(packed: BCNNPacked, *, path: str = "mxu",
     which is the zero-recompile contract the streaming engine
     (``serve/bcnn_engine.py``) relies on — and additionally supports
     ``swap(new_packed)``: zero-recompile weight hot-swap (see
-    ``PackedForward``). ``conv_fusion`` turns on the cross-layer fused
-    megakernel for the planner's same-resolution pairs; the hot-swap and
-    zero-recompile contracts are unchanged (``split_packed`` statics are
-    identical — the fused kernel consumes the same packed arrays).
-    ``plan`` — an `core/execution_plan.py::ExecutionPlan` carrying every
-    kernel choice at once; the other kwargs become no-ops when it is given.
+    ``PackedForward``). ``plan`` — an
+    `core/execution_plan.py::ExecutionPlan` carrying every kernel choice at
+    once; None means `core/execution_plan.py::default_plan`. A fused plan
+    keeps the hot-swap and zero-recompile contracts: the fused kernel
+    consumes the same packed arrays.
     """
     if plan is None:
         from repro.core import execution_plan
-        plan = execution_plan.build_plan(packed, path=path,
-                                         conv_strategy=conv_strategy,
-                                         conv_fusion=conv_fusion)
+        plan = execution_plan.default_plan(packed)
     return packed_model.PackedForward(packed, plan)
 
 

@@ -51,7 +51,7 @@ import numpy as np
 
 from repro.core import bconv, blinear
 # _is_weight_array: the SAME leaf predicate the hot-swap path uses
-# (split_packed / assert_swap_compatible) — a loaded artifact is documented
+# (packed_model.split / check_swap) — a loaded artifact is documented
 # as a valid swap payload, so the two must never diverge
 from repro.core.bcnn import BCNNPacked
 from repro.core.packed_model import _is_weight_array

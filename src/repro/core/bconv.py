@@ -48,17 +48,6 @@ class BConvParams(NamedTuple):
     bn_beta: jnp.ndarray
 
 
-DEFAULT_CONV_STRATEGY = "auto"   # "auto" | "direct" | "im2col"
-
-# Cross-layer conv fusion (kernels/xnor_conv_fused.py): fuse same-resolution
-# binary conv pairs so the intermediate bit map never touches HBM. Opt-in
-# (like the router tier): every deployment forward takes a ``conv_fusion``
-# override and falls back to this default when passed None. Fusion is
-# bit-exact with the sequential fold, so flipping it never changes outputs —
-# only the dataflow. configs/bcnn_cifar10.py re-exports this as CONV_FUSION.
-DEFAULT_CONV_FUSION = False
-
-
 class BConvPacked(NamedTuple):
     w_words: jnp.ndarray    # (O, ceil(FH*FW*I/32)) int32 — im2col layout
     thr: NBThreshold
@@ -141,7 +130,7 @@ def resolve_strategy(strategy: str | None, c: int,
     else fall back to "im2col" (general, handles per-position pad raggedness
     without re-packing the feature map).
     """
-    strategy = strategy or DEFAULT_CONV_STRATEGY
+    strategy = strategy or "auto"
     if strategy == "auto":
         have_hw = fp is None or fp.w_words_hw is not None
         strategy = ("direct" if c % bitpack.PACK == 0 and have_hw

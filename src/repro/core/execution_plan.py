@@ -1,34 +1,35 @@
-"""ExecutionPlan: every kernel-choice knob of the deployment forward in ONE
-static, hashable object (ROADMAP item 4 — the software analogue of the
-paper's per-platform accelerator specialization).
+"""ExecutionPlan: every kernel choice of a deployment forward in ONE static,
+hashable object (the software analogue of the paper's per-platform
+accelerator specialization).
 
-Before this module the choices were scattered as per-call-site flags:
+The plan holds:
 
 * the kernel ``path`` ("vpu" XNOR+popcount | "mxu" unpack-dot | "xla"
-  reference), resolved per engine by ``serve/bcnn_engine.py``;
-* the per-layer conv ``strategy`` ("direct" | "im2col"), resolved per *call*
-  by `core/bconv.py::resolve_strategy`;
-* the cross-layer fusion flag and the fused pair's spatial tile shape,
-  picked inside `kernels/ops.py::xnor_conv2d_pair` by
-  `kernels/xnor_conv_fused.py::pick_tiles`;
-* the LM decode GEMM mode ("bw" weight-only | "xnor" full-packed) on
+  reference);
+* the per-layer conv ``strategy`` ("direct" | "im2col") of the Table 2
+  network;
+* the cross-layer fusion flag and each fused pair's spatial tile shape
+  (`kernels/xnor_conv_fused.py`);
+* the LM decode GEMM mode ("bw" weight-only | "xnor" full-packed) of
   `models/xnor_lm.py::make_serving_engine`.
 
-``ExecutionPlan`` gathers them into one frozen dataclass of Python statics.
-It is hashable and contains no arrays, so a deployment forward can close
-over it at trace time — the zero-recompile contract (weights as jit
-arguments, statics closed over; see `core/bcnn.py::split_packed`) is
-untouched, and ``step_cache_size == 1`` survives tuning.
+It is a frozen dataclass of Python statics with no arrays, so a deployment
+forward closes over it at trace time: the zero-recompile contract (weights
+as jit arguments, statics closed over; `core/packed_model.py::split`) holds,
+and ``step_cache_size == 1`` survives tuning.
 
-``default_plan(packed, backend)`` reproduces today's heuristics bit-for-bit:
-"auto" path → mxu on TPU else xla (the `serve/bcnn_engine.py` rule), "auto"
-strategy → `core/bconv.py::resolve_strategy`, fusion →
-`core/bconv.py::DEFAULT_CONV_FUSION`, tiles →
-`kernels/xnor_conv_fused.py::pick_tiles`. The measured alternative is
-`kernels/autotune.py::autotune_packed`; tuned plans persist in the
+A plan comes from one of two places. ``default_plan(packed, backend)`` is
+the heuristic one, and the plan of every serving entry point that is given
+none (`core/bcnn.py::make_packed_forward`,
+`serve/bcnn_engine.py::BCNNEngine.from_packed`, the data-parallel and
+pipelined forwards, `serve/router.py::Router.from_packed`). The measured
+one is `kernels/autotune.py::autotune_packed`'s; tuned plans persist in the
 deployment artifact (`core/bcnn_artifact.py` ``tuning`` section) keyed by
 (backend, device kind, model geometry) and fall back to ``default_plan``
-when the key does not match the serving host.
+when the key does not match the serving host. Any other plan is a
+``dataclasses.replace`` of one of these (for example ``path="xla"`` for an
+on-chip reference, or ``conv_fusion=True``: with no ``group_tiles`` each
+fused pair takes `kernels/xnor_conv_fused.py::pick_tiles`' tiles).
 """
 from __future__ import annotations
 
@@ -40,23 +41,8 @@ import jax
 
 from repro.core import bcnn, bconv
 
-# Knob defaults mirrored from their historical homes, so a plan can be built
-# without touching the scattered sites it replaces.
 DEFAULT_LM_MODE = "bw"          # models/xnor_lm.py decode GEMM default
 PLAN_PATHS = ("vpu", "mxu", "xla")
-
-
-def resolve_path(path: str, backend: str | None = None) -> str:
-    """Resolve the "auto" kernel variant exactly like the serving engine
-    always has: the TPU-native MXU variant on TPU, the XLA reference
-    lowering everywhere else (Pallas would run in interpret mode)."""
-    if path != "auto":
-        if path not in PLAN_PATHS:
-            raise ValueError(f"unknown kernel path {path!r}; "
-                             f"use one of {PLAN_PATHS} or 'auto'")
-        return path
-    backend = backend or jax.default_backend()
-    return "mxu" if backend == "tpu" else "xla"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,7 +165,7 @@ def plan_key_fingerprint(key: dict) -> str:
 
 
 # ---------------------------------------------------------------------------
-# default_plan: today's heuristics, bit-for-bit
+# default_plan: today's heuristics
 # ---------------------------------------------------------------------------
 
 def _conv_resolution(idx: int, input_hw: tuple[int, int]) -> tuple[int, int]:
@@ -214,43 +200,26 @@ def default_group_tiles(packed, groups, *,
     return tuple(tiles)
 
 
-def build_plan(packed, *, path: str = "auto",
-               conv_strategy: str | None = None,
-               conv_fusion: bool | None = None,
-               lm_mode: str = DEFAULT_LM_MODE,
-               backend: str | None = None,
-               input_hw: tuple[int, int] = (32, 32),
-               tuned: bool = False) -> ExecutionPlan:
-    """Resolve legacy-style knobs into a concrete ``ExecutionPlan``.
+def default_plan(packed, backend: str | None = None, *,
+                 input_hw: tuple[int, int] = (32, 32)) -> ExecutionPlan:
+    """Today's heuristic choices as one plan: the plan of every entry point
+    that is given none, and the fallback whenever no (valid) tuned plan
+    exists.
 
-    This is the deprecation shim behind every forward's old
-    ``path=``/``conv_strategy=``/``conv_fusion=`` kwargs: the resolution
-    rules are the historical ones, applied once up front instead of per
-    call site — so a plan built from the old defaults computes bit-exactly
-    what the old threading did.
+    * path: the TPU-native "mxu" on a TPU, the "xla" reference lowering
+      elsewhere (Pallas would run in interpret mode);
+    * per Table 2 binary conv layer: ``bconv.resolve_strategy``'s "auto"
+      rule ("direct" on 32-aligned channels);
+    * fusion off, so no fused-pair tiles (``input_hw``, the image size a
+      fused pair's tiles are sized for, sizes nothing here);
+    * a network with no per-layer conv choice (Bi-Real Net): the path alone.
     """
-    rpath = resolve_path(path, backend)
+    path = "mxu" if (backend or jax.default_backend()) == "tpu" else "xla"
     if not isinstance(packed, bcnn.BCNNPacked):
-        # a network with no per-layer conv choices: the path alone
-        return ExecutionPlan(path=rpath, conv_strategy=(), lm_mode=lm_mode,
-                             tuned=tuned)
+        return ExecutionPlan(path=path, conv_strategy=())
     strategies = [None] * bcnn.N_LAYERS
     for idx in range(1, 6):
         fp = packed.convs[idx - 1]
         c = fp.k // (fp.fh * fp.fw)             # true input channel count
-        strategies[idx] = bconv.resolve_strategy(conv_strategy, c, fp)
-    fusion = (bconv.DEFAULT_CONV_FUSION if conv_fusion is None
-              else bool(conv_fusion))
-    groups = bcnn.plan_layer_groups(conv_fusion=fusion)
-    tiles = default_group_tiles(packed, groups, input_hw=input_hw)
-    return ExecutionPlan(path=rpath, conv_strategy=tuple(strategies),
-                         conv_fusion=fusion, group_tiles=tiles,
-                         lm_mode=lm_mode, tuned=tuned)
-
-
-def default_plan(packed, backend: str | None = None, *,
-                 input_hw: tuple[int, int] = (32, 32)) -> ExecutionPlan:
-    """Today's heuristic choices as one plan — the fallback whenever no
-    (valid) tuned plan exists. Bit-exact with the historical per-site
-    resolution: golden logits are unchanged (tests/test_autotune.py)."""
-    return build_plan(packed, backend=backend, input_hw=input_hw)
+        strategies[idx] = bconv.resolve_strategy("auto", c, fp)
+    return ExecutionPlan(path=path, conv_strategy=tuple(strategies))

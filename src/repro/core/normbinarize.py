@@ -79,7 +79,7 @@ def bn_denom(var: jnp.ndarray, eps: float) -> jnp.ndarray:
     serving tests and benchmark harnesses): the BN arithmetic must round
     identically in and out of jit, for ANY weights — whether they ride as
     constants (closure) or runtime arguments (the
-    ``core/bcnn.py::split_packed`` hot-swap path). XLA otherwise rewrites
+    ``core/packed_model.py::split`` hot-swap path). XLA otherwise rewrites
     ``x / sqrt(v)`` into ``x * rsqrt(v)`` / a division by a constant into
     a reciprocal multiply — 1-ulp differences the eager reference never
     sees. The barrier makes the divisor opaque, pinning the division as

@@ -15,8 +15,9 @@ The same bottleneck-stage structure drives pipeline-parallel stage assignment
 for the LM side (parallel/pipeline.py): eq. 12 is exactly the 1F1B pipeline
 steady-state rate law, with C_l = per-stage step time.
 
-benchmarks/table3.py asserts this module reproduces the paper's Table 3
-numbers exactly; tests/test_throughput.py covers the model's invariants.
+tests/test_throughput.py asserts that this module reproduces the paper's
+Table 3 cycle counts and Table 5 throughput, and covers the model's
+invariants.
 """
 from __future__ import annotations
 
@@ -28,7 +29,6 @@ from dataclasses import dataclass
 FREQ_HZ = 90e6          # paper §6.2: 90 MHz system clock
 PAPER_FPS = 6218        # paper §6.2
 PAPER_TOPS = 7.663      # paper abstract/Table 5
-PAPER_POWER_W = 8.2     # paper abstract
 
 
 @dataclass(frozen=True)
@@ -152,15 +152,6 @@ def optimize_parallelism(layers=BCNN_CONV_LAYERS, *, pe_budget: int = 112,
             break
         ps[max(fitting, key=est)] *= 2
     return {n: (ufs[n], ps[n], est(n)) for n in ps}
-
-
-def reproduce_table3() -> dict[str, tuple[int, int, int, int]]:
-    """(UF, P, Cycle_conv, Cycle_est) per layer with the paper's parameters."""
-    out = {}
-    for d in BCNN_CONV_LAYERS:
-        uf, p, _, _, _ = PAPER_TABLE3[d.name]
-        out[d.name] = (uf, p, cycle_conv(d), cycle_est(d, uf, p))
-    return out
 
 
 # --- Generalization: bottleneck-balanced stage partitioning -----------------

@@ -40,6 +40,7 @@ are ignored, never an error.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 
 import jax
@@ -205,12 +206,12 @@ def autotune_packed(packed, *, backend: str | None = None,
     x01 = jax.random.uniform(key, (batch, *input_hw, 3), jnp.float32)
 
     # 1. xla reference: per-layer inputs + outputs
+    ref_plan = dataclasses.replace(base, path="xla")
     inputs, refs = {}, {}
     h = x01
     for idx in range(bcnn.N_LAYERS):
         inputs[idx] = h
-        h = bcnn.apply_packed_layer(packed, idx, h, path="xla",
-                                    conv_strategy=base.strategy_for(idx))
+        h = bcnn.apply_packed_layer(packed, idx, h, ref_plan)
         refs[idx] = h
     logits_ref = h
 
@@ -239,7 +240,8 @@ def autotune_packed(packed, *, backend: str | None = None,
             label, t = _race(
                 [(f"fc{idx}:{p}",
                   lambda idx=idx, p=p: bcnn.apply_packed_layer(
-                      packed, idx, inputs[idx], path=p))],
+                      packed, idx, inputs[idx],
+                      dataclasses.replace(base, path=p)))],
                 refs[idx], timer=timer, reps=reps, warmup=warmup,
                 report_rows=rows)
             if label is None:

@@ -313,7 +313,7 @@ def binary_weight_matmul(a: jnp.ndarray, w_words: jnp.ndarray, *, k: int,
         raise ValueError(
             f"in_features {kk} needs ceil({kk}/32)={bitpack.packed_len(kk)} "
             f"packed weight words, got {kw} — weights must be packed along "
-            f"a 32-bit-aligned reduction axis (kernels/ops.py::pack_weights; "
+            f"a 32-bit-aligned reduction axis (core/bitpack.py::pack_pm1; "
             f"a ragged K < kw*32 is fine: the activation zero-padding "
             f"neutralizes the pad weight bits)")
     a2 = a.reshape(-1, kk)
@@ -340,11 +340,6 @@ def binary_weight_matmul(a: jnp.ndarray, w_words: jnp.ndarray, *, k: int,
     y = kern.binary_weight_matmul(a2, w_p, k=kk, scale=s, bm=bm, bn=bn,
                                   bkw=bkw, interpret=interpret)
     return y[:m_true, :n_true].reshape(*lead, n)
-
-
-def pack_weights(w_pm1: jnp.ndarray) -> jnp.ndarray:
-    """(N, K) ±1/real weights → (N, Kw) packed int32 (sign rule, eq. 4)."""
-    return bitpack.pack_pm1(w_pm1)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "q_block", "kv_block",

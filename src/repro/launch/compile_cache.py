@@ -1,9 +1,10 @@
 """One home for JAX's persistent compilation cache.
 
 Every entry point (``chip_smoke.py``, ``launch/serve_bcnn.py``,
-``launch/serve.py``, ``launch/train_bcnn.py``, ``benchmarks/fig7.py``)
-calls ``enable()`` before its first compile, so a second cold run in the
-same checkout reads its executables back instead of compiling them again.
+``launch/serve.py``, ``launch/train_bcnn.py``, and the benchmark's
+``bench/harness.py`` behind ``bench/run.py``) calls ``enable()`` before its
+first compile, so a second cold run in the same checkout reads its
+executables back instead of compiling them again.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this module
   sets no directory.

@@ -4,9 +4,9 @@ Multi-device harnesses on a plain-CPU host need
 ``--xla_force_host_platform_device_count=N`` in ``XLA_FLAGS``, and XLA
 reads that flag once, at backend init — i.e. it must be set BEFORE the
 first ``import jax`` anywhere in the process. This module therefore
-imports only ``os``/``sys`` so entry points (``launch/serve_bcnn.py``,
-``benchmarks/fig7.py``, ``benchmarks/run.py``) can import it above their
-jax import and key the decision on raw ``sys.argv``.
+imports only ``os``/``sys`` so an entry point (``launch/serve_bcnn.py``)
+can import it above its jax import and key the decision on raw
+``sys.argv``.
 """
 from __future__ import annotations
 

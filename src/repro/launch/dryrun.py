@@ -7,8 +7,7 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
 Lowers + compiles every assigned (architecture × input shape) cell on the
 16×16 single-pod mesh and the 2×16×16 multi-pod mesh, prints
 memory_analysis()/cost_analysis(), extracts the three roofline terms, and
-writes one JSON per cell under --out (read by benchmarks/roofline.py and
-EXPERIMENTS.md).
+writes one JSON per cell under --out.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun                      # all

@@ -356,14 +356,3 @@ def save_result(res: CellResult, out_dir: str):
     fname = f"{res.arch}__{res.shape}__{res.mesh}__{res.quant}.json"
     with open(os.path.join(out_dir, fname), "w") as f:
         json.dump(asdict(res), f, indent=1)
-
-
-def load_results(out_dir: str) -> list[dict]:
-    out = []
-    if not os.path.isdir(out_dir):
-        return out
-    for fn in sorted(os.listdir(out_dir)):
-        if fn.endswith(".json"):
-            with open(os.path.join(out_dir, fn)) as f:
-                out.append(json.load(f))
-    return out

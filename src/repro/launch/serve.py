@@ -2,8 +2,9 @@
 
 Demonstrates the paper's batch-size-insensitivity claim in its TPU form:
 requests are admitted the moment a slot frees, so throughput holds at
-small/irregular arrival batches (§6.3 / Fig. 7 analogue; benchmarks/fig7.py
-quantifies it).
+small/irregular arrival batches (§6.3 / Fig. 7 analogue). The chip
+benchmark (``bench/run.py``, cells in ``BENCHMARK.json``) measures it for
+the binary CNNs.
 
 Two model families share the one slot engine (``serve/engine.py``):
 

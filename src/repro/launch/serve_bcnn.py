@@ -82,8 +82,8 @@ def parse_priority_mix(spec: str) -> dict[str, int]:
 
 def resolve_plan(packed, args):
     """``--autotune`` / ``--tuning-cache`` → the ExecutionPlan to serve
-    with, or None (no tuning flags: the engines build the heuristic plan
-    from the per-knob CLI flags exactly as before).
+    with, or None (no tuning flags: the engines serve
+    ``core/execution_plan.py::default_plan``).
 
     Cache-first protocol: a usable ``tuning`` section in ``--tuning-cache``
     (or, failing that, ``--artifact``) whose (backend, device kind,
@@ -157,9 +157,7 @@ def serve_fleet(packed, x, args, plan=None):
             cooldown_s=pc.AUTOSCALE_COOLDOWN_S,
             interval_s=pc.AUTOSCALE_INTERVAL_S)
     router = Router.from_packed(
-        packed, n_replicas=args.replicas, n_slots=args.slots,
-        path=args.path, conv_strategy=args.conv_strategy,
-        conv_fusion=args.conv_fusion, plan=plan,
+        packed, n_replicas=args.replicas, n_slots=args.slots, plan=plan,
         max_queue=args.max_queue, history=max(4096, args.requests),
         online_reserve=args.online_reserve,
         bulk_chunk=args.bulk_chunk if args.bulk_chunk > 0 else None,
@@ -252,17 +250,6 @@ def main(argv=None):
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--rate", type=float, default=0.0,
                     help="Poisson arrival rate in req/s; 0 = all up front")
-    ap.add_argument("--path", default="auto",
-                    choices=["auto", "xla", "mxu", "vpu"],
-                    help="kernel path (auto: mxu on TPU, xla elsewhere)")
-    ap.add_argument("--conv-strategy", default=pc.CONV_STRATEGY,
-                    choices=["auto", "direct", "im2col"])
-    ap.add_argument("--conv-fusion", action="store_true",
-                    default=pc.CONV_FUSION,
-                    help="fuse the same-resolution conv pairs (CONV-3/4, "
-                         "CONV-5/6) into the cross-layer Pallas megakernel "
-                         "(kernels/xnor_conv_fused.py) — bit-exact, the "
-                         "intermediate bit map never touches HBM")
     ap.add_argument("--pipeline-stages", type=int, default=pc.PIPELINE_STAGES,
                     help="cut the 9-layer forward into N cost-balanced "
                          "pipeline stages over the local devices "
@@ -362,9 +349,7 @@ def main(argv=None):
         raise SystemExit("--rolling-swap needs --replicas >= 2 or "
                          "--autoscale (the rolling walk is a fleet-tier "
                          "operation)")
-    eng = BCNNEngine.from_packed(packed, n_slots=args.slots, path=args.path,
-                                 conv_strategy=args.conv_strategy,
-                                 conv_fusion=args.conv_fusion, plan=plan,
+    eng = BCNNEngine.from_packed(packed, n_slots=args.slots, plan=plan,
                                  pipeline_stages=args.pipeline_stages,
                                  pipeline_micro_batch=args.micro_batch,
                                  data_shards=args.data_shards,

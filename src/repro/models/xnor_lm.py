@@ -34,7 +34,7 @@ Three execution forms, mirroring `core/bcnn.py`:
   accumulation is integer-exact, so both modes agree bit-for-bit);
 * the serving adapter ``XnorLMServeModel`` — plugs the packed decode step
   into `serve/engine.py::ServingEngine` behind the model seam, with a
-  `core/bcnn.py::split_packed`-style static/array split so the engine's
+  `core/packed_model.py::split`-style static/array split so the engine's
   zero-recompile (``step_cache_size == 1``) and weight-hot-swap contracts
   are inherited unchanged.
 
@@ -392,7 +392,7 @@ def _is_arr(x) -> bool:
 
 def split_packed(packed: XnorLMPacked):
     """(array leaves, rebuild closure) — the hot-swap contract, mirroring
-    `core/bcnn.py::split_packed`: arrays ride as jit arguments (two packed
+    `core/packed_model.py::split`: arrays ride as jit arguments (two packed
     LMs with identical shapes hit the same executable — zero recompiles on
     ``ServingEngine.swap_params``), statics (k, BN eps) rebuild inside the
     trace."""

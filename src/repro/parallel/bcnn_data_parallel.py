@@ -36,11 +36,10 @@ results sliced back to N (rows never mix). So for a fixed
 ``ShardedForward.cache_size()`` — across every batch size, mirroring the
 zero-recompile contract of the engine and the stage pipeline.
 
-Measured curves: ``benchmarks/fig7.py --offline`` (throughput vs batch
-size × device count). Served through
-``serve/bcnn_engine.py::BCNNEngine.classify_batch`` when the engine is
-built with ``from_packed(data_shards=...)``. Operator guide:
-``docs/SERVING.md``.
+Served through ``serve/bcnn_engine.py::BCNNEngine.classify_batch`` when
+the engine is built with ``from_packed(data_shards=...)``; the chip
+benchmark's bulk cells (``bench/run.py``, ``BENCHMARK.json``) time that
+route. Operator guide: ``docs/SERVING.md``.
 """
 from __future__ import annotations
 
@@ -72,23 +71,16 @@ class DeploymentPlan(NamedTuple):
     micro_batch: int
     chunk: int
     stage_plan: StagePlan | None    # None: a network other than Table 2
-    conv_fusion: bool = False
-    fused_groups: tuple = ()   # per stage: the plan_layer_groups partition
 
     def describe(self) -> dict:
-        """JSON-ready plan metadata — embedded in every
-        ``benchmarks/fig7.py`` dump so a curve is reproducible from the
-        artifact alone. ``conv_fusion``/``fused_groups`` record the
-        cross-layer fusion plan (one layer-group partition per stage)."""
+        """JSON-ready plan metadata (the kernel choices are the
+        ``ExecutionPlan``'s, ``ShardedForward.exec_plan.describe()``)."""
         return {"data_shards": self.data_shards,
                 "n_stages": self.n_stages,
                 "micro_batch": self.micro_batch,
                 "chunk": self.chunk,
                 "stage_bounds": (None if self.stage_plan is None
-                                 else list(self.stage_plan.bounds)),
-                "conv_fusion": bool(self.conv_fusion),
-                "fused_groups": [[list(g) for g in stage]
-                                 for stage in self.fused_groups]}
+                                 else list(self.stage_plan.bounds))}
 
 
 class ShardedForward:
@@ -119,21 +111,16 @@ class ShardedForward:
 
     ``cache_size()`` is the one-compilation-per-plan contract (the chunk
     jit, or the max per-stage jit cache across shard pipelines) and must
-    stay 1 — guarded by tests/test_bcnn_data_parallel.py and asserted
-    inside ``benchmarks/fig7.py --offline``.
+    stay 1 — guarded by tests/test_bcnn_data_parallel.py.
     """
 
     def __init__(self, packed: PackedModel, mesh, micro_batch: int, *,
                  n_stages: int = 1, devices: Sequence | None = None,
-                 path: str = "mxu", conv_strategy: str | None = None,
-                 conv_fusion: bool | None = None,
                  plan: "xplan.ExecutionPlan | None" = None):
         if micro_batch < 1:
             raise ValueError(f"micro_batch must be >= 1, got {micro_batch}")
-        if plan is None:    # deprecated per-knob kwargs → a shim plan
-            plan = xplan.build_plan(packed, path=path,
-                                    conv_strategy=conv_strategy,
-                                    conv_fusion=conv_fusion)
+        if plan is None:
+            plan = xplan.default_plan(packed)
         packed.check_plan(plan)
         self.exec_plan = plan           # the ExecutionPlan (kernel choices)
         self.mesh = mesh
@@ -147,13 +134,7 @@ class ShardedForward:
         stage_plan = plan_bcnn_stages(n_stages) if table2 else None
         self.plan = DeploymentPlan(
             data_shards=shards, n_stages=n_stages, micro_batch=micro_batch,
-            chunk=shards * micro_batch, stage_plan=stage_plan,
-            conv_fusion=plan.conv_fusion,
-            fused_groups=tuple(
-                bcnn.plan_layer_groups(stage_plan.bounds[s],
-                                       stage_plan.bounds[s + 1],
-                                       conv_fusion=plan.conv_fusion)
-                for s in range(n_stages)) if table2 else ())
+            chunk=shards * micro_batch, stage_plan=stage_plan)
         self._n_classes = packed.n_classes
         if devices is None:
             devices = list(mesh.devices.flat)
@@ -292,9 +273,7 @@ def chunk_unit(packed: PackedModel, mesh, plan, chunk: int):
 def make_sharded_forward(packed: PackedModel, mesh=None, *,
                          data_shards: int | None = None,
                          micro_batch: int = 8, n_stages: int = 1,
-                         devices=None, path: str = "mxu",
-                         conv_strategy: str | None = None,
-                         conv_fusion: bool | None = None,
+                         devices=None,
                          plan: "xplan.ExecutionPlan | None" = None
                          ) -> ShardedForward:
     """Close packed artifacts over a batch-sharded deployment forward.
@@ -314,6 +293,8 @@ def make_sharded_forward(packed: PackedModel, mesh=None, *,
     * ``devices`` — explicit placement for the 2-D grid (flattened
       row-major: shard-major, stage-minor); defaults to the mesh's
       devices cycled as needed.
+    * ``plan`` — the `core/execution_plan.py::ExecutionPlan` every shard
+      runs; None means `core/execution_plan.py::default_plan`.
 
     The returned ``ShardedForward`` is bit-exact with ``forward_packed``
     for any batch size and compiles exactly once per plan.
@@ -340,6 +321,4 @@ def make_sharded_forward(packed: PackedModel, mesh=None, *,
             raise ValueError(f"mesh has {have} data shards, "
                              f"data_shards={data_shards} requested")
     return ShardedForward(packed, mesh, micro_batch, n_stages=n_stages,
-                          devices=devices, path=path,
-                          conv_strategy=conv_strategy,
-                          conv_fusion=conv_fusion, plan=plan)
+                          devices=devices, plan=plan)

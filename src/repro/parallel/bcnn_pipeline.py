@@ -35,18 +35,17 @@ spatial dims, then FC stages):
 
 The schedule is the inference-only fill/drain pipeline: with S stages and
 M micro-batches a forward takes M+S−1 ticks, modeled analytically by
-``parallel.pipeline.schedule_1f1b(..., fwd_bwd_mult=1.0)``. Measured
-curves: ``benchmarks/fig7.py --pipeline``. Docs: ``docs/PIPELINE.md``.
+``parallel.pipeline.schedule_1f1b(..., fwd_bwd_mult=1.0)``. Docs:
+``docs/PIPELINE.md``.
 """
 from __future__ import annotations
 
-import time
 from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 
-from repro.core import bcnn, bitpack, execution_plan as xplan
+from repro.core import bcnn, bitpack, execution_plan as xplan, packed_model
 from repro.core.throughput import (BCNN_CONV_LAYERS, BCNN_FC_SPECS,
                                    balance_stages, cycle_conv)
 from repro.parallel.pipeline import schedule_1f1b, stage_costs_from_bounds
@@ -176,7 +175,7 @@ def _make_stage_fn(rebuild: Callable, a: int, b: int, *,
     Statics (layer indices, packed k's, filter sizes, and every kernel
     choice in the ``core/execution_plan.py::ExecutionPlan``) are closed
     over while the weight arrays arrive as the first jit argument (the
-    ``core/bcnn.py::split_packed`` hot-swap contract), so the returned
+    ``core/packed_model.py::split`` hot-swap contract), so the returned
     function has a shape-only jit signature — the same contract as
     ``core/bcnn.py::make_packed_forward``, per stage — and a weight swap
     with identical shapes reuses the compiled executable.
@@ -220,40 +219,26 @@ class PipelinedForward:
 
     def __init__(self, packed: bcnn.BCNNPacked, stage_plan: StagePlan,
                  devices: Sequence, micro_batch: int, *,
-                 path: str = "mxu", conv_strategy: str | None = None,
-                 conv_fusion: bool | None = None,
                  plan: "xplan.ExecutionPlan | None" = None):
         if micro_batch < 1:
             raise ValueError(f"micro_batch must be >= 1, got {micro_batch}")
-        if plan is None:    # deprecated per-knob kwargs → a shim plan
-            plan = xplan.build_plan(packed, path=path,
-                                    conv_strategy=conv_strategy,
-                                    conv_fusion=conv_fusion)
+        if plan is None:
+            plan = xplan.default_plan(packed)
         self.plan = stage_plan          # the StagePlan (stage cut points)
         self.exec_plan = plan           # the ExecutionPlan (kernel choices)
         self.micro_batch = micro_batch
-        self.conv_fusion = plan.conv_fusion
         self._packed = packed
         self._n_classes = packed.fc3_w_words.shape[0]
         # stage s runs on devices[s % len(devices)]: fewer devices than
         # stages degrades gracefully (stages co-resident, still correct)
         self.devices = tuple(devices[s % len(devices)]
                              for s in range(stage_plan.n_stages))
-        arrays, rebuild = bcnn.split_packed(packed)
+        arrays, rebuild = packed_model.split(packed)
         self._stage_arrays = self._place_arrays(arrays)
         self._stage_fns = [
             jax.jit(_make_stage_fn(rebuild, stage_plan.bounds[s],
                                    stage_plan.bounds[s + 1], plan=plan))
             for s in range(stage_plan.n_stages)]
-
-    def fused_groups(self) -> tuple:
-        """The per-stage fusion plans (for benchmark/plan metadata): one
-        ``plan_layer_groups(a, b)`` tuple per stage."""
-        return tuple(
-            bcnn.plan_layer_groups(self.plan.bounds[s],
-                                   self.plan.bounds[s + 1],
-                                   conv_fusion=self.conv_fusion)
-            for s in range(self.n_stages))
 
     def _place_arrays(self, arrays) -> list:
         """One device-resident copy of the weight arrays per stage (the
@@ -305,8 +290,8 @@ class PipelinedForward:
     def swap(self, new_packed: bcnn.BCNNPacked) -> None:
         """Hot-swap the served weights across every stage; zero recompiles
         (identical shapes → each stage's jit executable is reused; checked
-        by ``core/bcnn.py::assert_swap_compatible``)."""
-        arrays = bcnn.assert_swap_compatible(self._packed, new_packed)
+        by ``core/packed_model.py::check_swap``)."""
+        arrays = packed_model.check_swap(self._packed, new_packed)
         self._packed = new_packed
         self._stage_arrays = self._place_arrays(arrays)
 
@@ -316,30 +301,9 @@ class PipelinedForward:
         swap (each stage only ever sees the fixed micro-batch shapes)."""
         return max(int(f._cache_size()) for f in self._stage_fns)
 
-    def stage_times(self, x01: jnp.ndarray, reps: int = 3) -> list[float]:
-        """Measured per-stage seconds for one micro-batch (blocking each
-        stage in turn — a diagnostic for the eq. 12 balance, not the
-        pipelined wall-clock). Feeds the fig7 ``--pipeline`` stage table."""
-        h = pad_rows(jnp.asarray(x01[:self.micro_batch]), self.micro_batch)
-        times = []
-        for s, fn in enumerate(self._stage_fns):
-            h = jax.device_put(h, self.devices[s])
-            w = self._stage_arrays[s]
-            jax.block_until_ready(fn(w, h))         # compile + warm
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                out = fn(w, h)
-            jax.block_until_ready(out)
-            times.append((time.perf_counter() - t0) / reps)
-            h = out
-        return times
-
 
 def make_pipelined_forward(packed: bcnn.BCNNPacked, *, n_stages: int,
                            micro_batch: int = 1, devices=None,
-                           path: str = "mxu",
-                           conv_strategy: str | None = None,
-                           conv_fusion: bool | None = None,
                            plan: "xplan.ExecutionPlan | None" = None
                            ) -> PipelinedForward:
     """Close packed artifacts over an N-stage pipelined deployment forward.
@@ -350,7 +314,9 @@ def make_pipelined_forward(packed: bcnn.BCNNPacked, *, n_stages: int,
     of ``jax.devices()``; pass an explicit list to choose placement).
     ``micro_batch`` is the streaming granule — smaller means more overlap
     (and more dispatch overhead); the engine default of 1 mirrors the
-    paper's one-image-per-stage pipeline.
+    paper's one-image-per-stage pipeline. ``plan`` — the
+    `core/execution_plan.py::ExecutionPlan` every stage runs; None means
+    `core/execution_plan.py::default_plan`.
 
     The returned ``PipelinedForward`` accepts any batch size N (including
     N < micro_batch) with zero recompiles, so ``BCNNEngine`` can use it as
@@ -360,5 +326,4 @@ def make_pipelined_forward(packed: bcnn.BCNNPacked, *, n_stages: int,
     if devices is None:
         devices = jax.devices()
     return PipelinedForward(packed, stage_plan, devices, micro_batch,
-                            path=path, conv_strategy=conv_strategy,
-                            conv_fusion=conv_fusion, plan=plan)
+                            plan=plan)

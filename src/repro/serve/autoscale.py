@@ -95,8 +95,8 @@ class AutoscaleConfig:
 
 @dataclass(frozen=True)
 class ScaleEvent:
-    """One executed scale event (the replica-count timeline the
-    ``benchmarks/fig7.py --autoscale`` load step records)."""
+    """One executed scale event (an entry of the fleet's replica-count
+    timeline)."""
     t: float                       # router-clock time of the decision
     direction: int                 # +1 (up) or -1 (down)
     n_replicas: int                # fleet size AFTER the event

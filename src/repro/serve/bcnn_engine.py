@@ -18,8 +18,9 @@ eq. 8 comparators):
   every occupied slot completes at the end of its step and frees
   immediately for the next queued request;
 * per-request latency (submit → done) and aggregate throughput accounting
-  (``serve/slots.latency_stats``: p50/p95/p99) — the measured curve behind
-  ``benchmarks/fig7.py --online``.
+  (``serve/slots.latency_stats``: p50/p95/p99). The chip benchmark
+  (``bench/run.py``, cell ``bcnn-online-poisson`` in ``BENCHMARK.json``)
+  times this engine from the client's side.
 
 The step's forward can be the single-device packed closure
 (``core/bcnn.py::make_packed_forward``) or — with
@@ -113,8 +114,6 @@ class BCNNEngine:
 
     @classmethod
     def from_packed(cls, packed: PackedModel, *, n_slots: int = 8,
-                    path: str = "auto", conv_strategy: str | None = None,
-                    conv_fusion: bool | None = None,
                     plan=None, autotune: bool = False,
                     pipeline_stages: int = 1,
                     pipeline_micro_batch: int = 1,
@@ -148,28 +147,21 @@ class BCNNEngine:
         requests is untouched. ``data_shards=0`` (default) disables the
         bulk path.
 
-        ``conv_fusion`` (None → the ``core/bconv.py`` default) turns on the
-        cross-layer fused conv megakernel inside whichever forward is built
-        — bit-exact, and the ``step_cache_size``/hot-swap contracts are
-        unchanged (the fused kernel consumes the same packed arrays).
-
         ``plan`` — a ``core/execution_plan.py::ExecutionPlan`` carrying
         EVERY kernel choice at once (path, per-layer conv strategy, fusion
-        + tiles, LM mode). When given, the per-knob kwargs above are
-        ignored; when omitted they build the equivalent plan (deprecated
-        shims — new code should pass a plan). ``autotune=True`` measures
-        one (``kernels/autotune.py::autotune_packed``) on this device
-        first; serving contracts are identical either way (a plan is
-        static — trace-time only).
+        + tiles), shared by every forward the engine builds; None means
+        ``core/execution_plan.py::default_plan``. ``autotune=True`` (with
+        no plan) measures one (``kernels/autotune.py::autotune_packed``)
+        on this device first; serving contracts are identical either way
+        (a plan is static — trace-time only), fused plans included: the
+        fused kernel consumes the same packed arrays.
         """
-        from repro.core import execution_plan as _xp
         if autotune and plan is None:
             from repro.kernels.autotune import autotune_packed
             plan = autotune_packed(packed)
-        if plan is None:    # deprecated per-knob kwargs → a shim plan
-            plan = _xp.build_plan(packed, path=path,
-                                  conv_strategy=conv_strategy,
-                                  conv_fusion=conv_fusion)
+        if plan is None:
+            from repro.core import execution_plan
+            plan = execution_plan.default_plan(packed)
         if pipeline_stages > 1:
             from repro.parallel.bcnn_pipeline import make_pipelined_forward
             fwd = make_pipelined_forward(

@@ -58,7 +58,7 @@ def _item_size(item: Any) -> int:
 class SwapTicket:
     """Handle for an enqueued weight swap: ``wait()`` blocks until the
     replica thread executed it (or re-raises the failure, e.g. an
-    incompatible replacement rejected by ``assert_swap_compatible``)."""
+    incompatible replacement rejected by ``check_swap``)."""
 
     def __init__(self):
         self._event = threading.Event()

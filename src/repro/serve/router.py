@@ -62,8 +62,7 @@ million-user tier the ROADMAP names:
 Deterministic tests use ``threaded=False``: no worker threads, the caller
 ``pump()``s the router (dispatch + every replica + one autoscaler step)
 on one thread with an injected clock. The CLI (``launch/serve_bcnn.py
---replicas/--autoscale``) and the ``benchmarks/fig7.py --router``/
-``--autoscale`` load sweeps run ``threaded=True``.
+--replicas/--autoscale``) runs ``threaded=True``.
 """
 from __future__ import annotations
 
@@ -76,7 +75,7 @@ from typing import Any, Callable, Iterable, Sequence
 
 import numpy as np
 
-from repro.core.bcnn import assert_swap_compatible
+from repro.core.packed_model import check_swap
 from repro.serve.autoscale import AutoscaleConfig, FleetAutoscaler, \
     run_controller
 from repro.serve.bcnn_engine import BCNNEngine
@@ -317,9 +316,7 @@ class Router:
     # ---------------------------------------------------------- construction
     @classmethod
     def from_packed(cls, packed, *, n_replicas: int = 2,
-                    n_slots: int | None = None, path: str = "auto",
-                    conv_strategy: str | None = None,
-                    conv_fusion: bool | None = None,
+                    n_slots: int | None = None,
                     plan=None, autotune: bool = False,
                     warmup: bool = True,
                     clock: Callable[[], float] = time.perf_counter,
@@ -328,15 +325,15 @@ class Router:
         router. Each replica owns its own jit closure (so each compiles
         exactly once: ``step_cache_size == 1`` *per replica*); ``warmup``
         compiles them before any traffic so the first requests don't pay
-        N compilations. ``conv_fusion`` threads to every replica's forward
-        (the cross-layer fused megakernel — bit-exact, same contracts).
-        The same factory is retained for ``scale_up``, so an elastically
-        spawned replica is configured identically and built from the
-        fleet's CURRENT packed artifact (post-swap if a rolling swap is
-        in flight).
+        N compilations. The same factory is retained for ``scale_up``, so
+        an elastically spawned replica is configured identically and built
+        from the fleet's CURRENT packed artifact (post-swap if a rolling
+        swap is in flight).
 
         ``plan`` / ``autotune``: one ``core/execution_plan.py::ExecutionPlan``
-        for the WHOLE fleet. With ``autotune=True`` (and no explicit plan)
+        for the WHOLE fleet; None means
+        ``core/execution_plan.py::default_plan``. With ``autotune=True``
+        (and no explicit plan)
         the candidate space is measured exactly once
         (``kernels/autotune.py::autotune_packed``) BEFORE the factory is
         captured — every initial replica, every ``scale_up`` spawn, and
@@ -349,10 +346,8 @@ class Router:
             from repro.kernels.autotune import autotune_packed
             plan = autotune_packed(packed)     # tune once, share fleet-wide
         if plan is None:
-            from repro.core import execution_plan as _xp
-            plan = _xp.build_plan(packed, path=path,
-                                  conv_strategy=conv_strategy,
-                                  conv_fusion=conv_fusion)
+            from repro.core import execution_plan
+            plan = execution_plan.default_plan(packed)
 
         def make_engine(p):
             return BCNNEngine.from_packed(p, plan=plan,
@@ -521,8 +516,8 @@ class Router:
         feeding the others), wait for it to drain, swap on its idle engine
         (``BCNNEngine.swap_packed`` — zero recompiles), resume, move on.
         Returns the number of replicas swapped. An incompatible
-        replacement is rejected upfront (``core/bcnn.py::
-        assert_swap_compatible`` against the fleet's current artifact)
+        replacement is rejected upfront (``core/packed_model.py::
+        check_swap`` against the fleet's current artifact)
         before ANY fleet state changes, so a failed swap leaves the fleet
         consistent. The fleet's target epoch and packed artifact advance
         BEFORE the walk: a scale-up racing the swap spawns its replica on
@@ -530,7 +525,7 @@ class Router:
         (or past) the target epoch."""
         with self._scale_lock:
             if self._current_packed is not None:
-                assert_swap_compatible(self._current_packed, new_packed)
+                check_swap(self._current_packed, new_packed)
             with self._lock:
                 self._fleet_epoch += 1
                 target = self._fleet_epoch

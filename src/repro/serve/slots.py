@@ -11,7 +11,7 @@ slot. What differs per engine is the step itself (autoregressive decode in
 * monotone request-id assignment and a FIFO admission queue,
 * slot occupancy and reuse (a freed slot is immediately re-admittable),
 * per-request latency stamps (submit → admit → done) feeding the
-  p50/p95/p99 accounting in ``benchmarks/fig7.py --online``,
+  p50/p95/p99 accounting of ``latency_stats`` (engine and router stats),
 * the span log (``SpanLog``) that times the parts of an engine's step on
   the same clock as those stamps.
 
@@ -285,8 +285,8 @@ def latency_stats(requests: Iterable[Request],
     submission to last completion. A zero-length span (e.g. a single
     completed request: its submission IS the span's start and end to clock
     resolution) carries no rate information, so ``throughput`` is ``None``
-    there — never ``inf``/``nan``, which are not JSON and broke the
-    ``benchmarks/fig7.py --json`` artifact. Empty input → ``{"n": 0}``.
+    there — never ``inf``/``nan``, which are not JSON. Empty input →
+    ``{"n": 0}``.
 
     Only fully stamped requests contribute: an unfinished request's
     ``latency``/``queue_wait`` are ``None`` (not a number), so queued or

@@ -32,13 +32,14 @@ Recipe + operator guide: ``docs/TRAINING.md``.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import bcnn
+from repro.core import bcnn, execution_plan
 from repro.data import SyntheticImages
 from repro.train import checkpoint as ckpt_lib
 from repro.train import optimizer as opt_lib
@@ -150,10 +151,11 @@ def train(*, steps: int, batch: int = 64, lr: float = 2e-3, seed: int = 0,
 
 
 def evaluate(params: bcnn.BCNNParams, *, batch: int = 64, seed: int = 0,
-             n_batches: int = 4, conv_strategy: str | None = None) -> dict:
+             n_batches: int = 4) -> dict:
     """Held-out agreement check of the paper's full life cycle: fold the
     trained params and compare the deployment forward
-    (``core/bcnn.py::forward_packed``) against the training-graph oracle
+    (``core/bcnn.py::forward_packed``, on the default plan's kernels in
+    their "xla" form) against the training-graph oracle
     (``core/bcnn.py::forward_eval``) on fresh synthetic batches.
 
     Returns ``{"acc_eval", "acc_packed", "agree", "n"}`` (fractions).
@@ -162,12 +164,13 @@ def evaluate(params: bcnn.BCNNParams, *, batch: int = 64, seed: int = 0,
     """
     data = SyntheticImages(global_batch=batch, seed=seed)
     packed = bcnn.fold_model(params)
+    plan = dataclasses.replace(execution_plan.default_plan(packed),
+                               path="xla")
     n = correct_eval = correct_packed = agree = 0
     for b in range(n_batches):
         x, y = data.batch(10_000 + b)
         le = bcnn.forward_eval(params, jnp.asarray(x))
-        lp = bcnn.forward_packed(packed, jnp.asarray(x), path="xla",
-                                 conv_strategy=conv_strategy)
+        lp = bcnn.forward_packed(packed, jnp.asarray(x), plan=plan)
         pe = np.asarray(jnp.argmax(le, -1))
         pp = np.asarray(jnp.argmax(lp, -1))
         correct_eval += int((pe == y).sum())

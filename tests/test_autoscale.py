@@ -10,12 +10,15 @@ on every replica that EVER existed (retired included). The co-scheduling
 tests pin the ``online_reserve`` contract: a reserve-blocked bulk chunk
 parks aside and lets online traffic queued behind it dispatch.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import bcnn
+from repro.core.execution_plan import default_plan
 from repro.serve import (AutoscaleConfig, BCNNEngine, RequestClass, Router,
                          RouterShutdown)
 
@@ -57,9 +60,14 @@ def packed_b():
     return bcnn.fold_model(bcnn.init(jax.random.PRNGKey(1)))
 
 
+def xla_plan(packed):
+    return dataclasses.replace(default_plan(packed), path="xla")
+
+
 def packed_router(packed, clock, *, n_replicas=1, autoscale=None, **kw):
     return Router.from_packed(packed, n_replicas=n_replicas, n_slots=2,
-                              path="xla", threaded=False, clock=clock,
+                              plan=xla_plan(packed), threaded=False,
+                              clock=clock,
                               autoscale=autoscale, **kw)
 
 
@@ -96,7 +104,7 @@ def test_scale_up_spawns_warm_identical_replica(packed_a):
     assert rep.step_cache_size == 1          # warmed before taking traffic
     x = np.random.default_rng(0).random((4, 32, 32, 3)).astype(np.float32)
     ref = np.asarray(bcnn.forward_packed(packed_a, jnp.asarray(x),
-                                         path="xla"))
+                                         plan=xla_plan(packed_a)))
     np.testing.assert_array_equal(router.classify_batch(x), ref)
     assert all(r.step_cache_size == 1 for r in router.replicas_ever)
 
@@ -162,7 +170,7 @@ def test_scale_up_racing_rolling_swap_lands_on_post_swap_epoch(
     assert all(r.epoch == 1 for r in router.replicas)
     x = np.random.default_rng(3).random((3, 32, 32, 3)).astype(np.float32)
     ref_b = np.asarray(bcnn.forward_packed(packed_b, jnp.asarray(x),
-                                           path="xla"))
+                                           plan=xla_plan(packed_b)))
     # every replica (the spawned one included) serves the NEW weights
     for im, ref in zip(x, ref_b):
         for rep_id in range(3):
@@ -226,7 +234,7 @@ def test_chunk_clamps_to_bulk_budget_under_reserve():
 
 def test_monopoly_chunk_without_reserve_still_serves():
     """reserve=0 keeps the pre-elastic behavior: one whole-batch chunk is
-    legal (the bulk-monopoly baseline fig7 --autoscale compares against)."""
+    legal (the bulk-monopoly baseline)."""
     r = toy_router(n_slots=2, dispatch_depth=4, online_reserve=0)
     out = r.classify_batch(np.stack([img(i + 1) for i in range(8)]),
                            cls="bulk", chunk=8)
@@ -250,9 +258,9 @@ def test_load_step_acceptance_one_to_n_to_one(packed_a, packed_b):
     images = np.random.default_rng(7).random((n, 32, 32, 3)).astype(
         np.float32)
     ref = {0: np.asarray(bcnn.forward_packed(packed_a, jnp.asarray(images),
-                                             path="xla")),
+                                             plan=xla_plan(packed_a))),
            1: np.asarray(bcnn.forward_packed(packed_b, jnp.asarray(images),
-                                             path="xla"))}
+                                             plan=xla_plan(packed_b)))}
     online, bulk_idx = [], []
     # low phase: a trickle the lone replica absorbs
     for i in range(4):

@@ -18,6 +18,8 @@ The hard invariants:
 * zero-recompile — a tuned engine keeps ``step_cache_size == 1`` across
   the occupancy sweep AND a weight hot-swap.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,6 +31,11 @@ from repro.kernels import xnor_conv_fused as kfused
 from repro.parallel import bcnn_data_parallel as bdp
 from repro.parallel import bcnn_pipeline as bp
 from repro.serve import BCNNEngine
+
+
+def _plan(packed, path):
+    """``default_plan(packed)`` on ``path``'s kernels."""
+    return dataclasses.replace(xplan.default_plan(packed), path=path)
 
 
 class FakeTimer:
@@ -63,7 +70,7 @@ def images():
 @pytest.fixture(scope="module")
 def ref_logits(packed, images):
     return np.asarray(bcnn.forward_packed(packed, jnp.asarray(images),
-                                          path="xla"))
+                                          plan=_plan(packed, "xla")))
 
 
 # ----------------------------------------------------------- candidate space
@@ -137,11 +144,11 @@ def test_fake_timer_tuning_is_deterministic(packed, tuned_plan):
 
 def test_default_plan_matches_legacy_resolution(packed):
     """``default_plan`` reproduces the historical per-site heuristics:
-    resolver strategies, fusion default, pick_tiles tiles."""
+    resolver strategies, fusion off (so no tiles)."""
     plan = xplan.default_plan(packed, "cpu")
     assert plan.tuned is False
     assert plan.path == "xla"                # "auto" off-TPU
-    assert plan.conv_fusion == bconv.DEFAULT_CONV_FUSION
+    assert plan.conv_fusion is False and plan.group_tiles == ()
     for idx in range(1, 6):
         fp = packed.convs[idx - 1]
         c = fp.k // (fp.fh * fp.fw)
@@ -262,5 +269,5 @@ def test_tuned_engine_one_compile_across_swap(packed, images, tuned_plan):
     out = eng.run()
     assert eng.step_cache_size == 1, "hot-swap must not recompile"
     ref_b = np.asarray(bcnn.forward_packed(
-        packed_b, jnp.asarray(images[:1]), path="xla"))
+        packed_b, jnp.asarray(images[:1]), plan=_plan(packed_b, "xla")))
     np.testing.assert_array_equal(np.asarray(out[rid]), ref_b[0])

@@ -1,11 +1,14 @@
 """The paper's BCNN end to end: training graph ≡ eval graph ≡ packed
 deployment graph (XNOR + fused eq. 8 comparators)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import bcnn
+from repro.core.execution_plan import default_plan
 
 
 @pytest.fixture(scope="module")
@@ -40,7 +43,8 @@ def test_eval_packed_agreement(trained):
     params, x = trained
     packed = bcnn.fold_model(params)
     le = bcnn.forward_eval(params, x)
-    lp = bcnn.forward_packed(packed, x, path="xla")
+    lp = bcnn.forward_packed(
+        packed, x, plan=dataclasses.replace(default_plan(packed), path="xla"))
     np.testing.assert_array_equal(np.argmax(np.asarray(le), -1),
                                   np.argmax(np.asarray(lp), -1))
     # logits agree to BN-arithmetic tolerance (integer y_l is exact; the
@@ -53,8 +57,11 @@ def test_eval_packed_agreement(trained):
 def test_packed_paths_agree(trained, path):
     params, x = trained
     packed = bcnn.fold_model(params)
-    ref = bcnn.forward_packed(packed, x[:2], path="xla")
-    out = bcnn.forward_packed(packed, x[:2], path=path)
+    plan = default_plan(packed)
+    ref = bcnn.forward_packed(packed, x[:2],
+                              plan=dataclasses.replace(plan, path="xla"))
+    out = bcnn.forward_packed(packed, x[:2],
+                              plan=dataclasses.replace(plan, path=path))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                rtol=1e-5, atol=1e-5)
 

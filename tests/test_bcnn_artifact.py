@@ -3,6 +3,7 @@ save→load roundtrip of the packed BCNN (including the int32 XNOR weight
 words in both conv layouts and the static Python leaves), golden-logit
 parity of the loaded net, CRC/version/format integrity rejection, and
 fold provenance in the manifest."""
+import dataclasses
 import json
 import os
 
@@ -11,7 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import bcnn, bcnn_artifact
+from repro.core import bcnn, bcnn_artifact, packed_model
+from repro.core.execution_plan import default_plan
 
 
 @pytest.fixture(scope="module")
@@ -45,7 +47,7 @@ def test_roundtrip_bit_exact(saved, packed):
     assert type(loaded.fc3_k) is int
     assert type(loaded.convs[0].k) is int
     # swap-compatibility is the machine-checked version of the same claim
-    bcnn.assert_swap_compatible(packed, loaded)
+    packed_model.check_swap(packed, loaded)
 
 
 def test_golden_logit_parity(saved, packed):
@@ -54,9 +56,10 @@ def test_golden_logit_parity(saved, packed):
     x = jnp.asarray(np.random.default_rng(1).random(
         (3, 32, 32, 3)).astype(np.float32))
     loaded = bcnn_artifact.load_packed(saved)
+    plan = dataclasses.replace(default_plan(packed), path="xla")
     np.testing.assert_array_equal(
-        np.asarray(bcnn.forward_packed(loaded, x, path="xla")),
-        np.asarray(bcnn.forward_packed(packed, x, path="xla")))
+        np.asarray(bcnn.forward_packed(loaded, x, plan=plan)),
+        np.asarray(bcnn.forward_packed(packed, x, plan=plan)))
 
 
 def test_provenance_recorded(saved):
@@ -132,9 +135,10 @@ def test_pre_tuning_version1_artifact_loads_bit_exact(saved, packed):
     loaded = bcnn_artifact.load_packed(saved)
     x = jnp.asarray(np.random.default_rng(2).random(
         (2, 32, 32, 3)).astype(np.float32))
+    plan = dataclasses.replace(default_plan(packed), path="xla")
     np.testing.assert_array_equal(
-        np.asarray(bcnn.forward_packed(loaded, x, path="xla")),
-        np.asarray(bcnn.forward_packed(packed, x, path="xla")))
+        np.asarray(bcnn.forward_packed(loaded, x, plan=plan)),
+        np.asarray(bcnn.forward_packed(packed, x, plan=plan)))
     assert bcnn_artifact.load_tuning(saved) is None
     # and the version floor still holds below the compat window
     man["version"] = bcnn_artifact.MIN_VERSION - 1

@@ -14,6 +14,7 @@ The hard invariants, per the paper's large-batch §6.3/Fig. 7 scenario:
   different (simulated host) devices; subprocess-isolated like
   tests/test_bcnn_pipeline.py so THIS process keeps seeing 1 device.
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -25,9 +26,15 @@ import numpy as np
 import pytest
 
 from repro.core import bcnn
+from repro.core.execution_plan import default_plan
 from repro.launch.mesh import make_data_mesh
 from repro.parallel.bcnn_data_parallel import make_sharded_forward
 from repro.serve import BCNNEngine
+
+
+def _plan(packed, path):
+    """``default_plan(packed)`` on ``path``'s kernels."""
+    return dataclasses.replace(default_plan(packed), path=path)
 
 
 @pytest.fixture(scope="module")
@@ -43,7 +50,7 @@ def images():
 @pytest.fixture(scope="module")
 def ref_logits(packed, images):
     return np.asarray(bcnn.forward_packed(packed, jnp.asarray(images),
-                                          path="xla"))
+                                          plan=_plan(packed, "xla")))
 
 
 # ----------------------------------------------------------------- parity
@@ -52,7 +59,7 @@ def test_parity_with_forward_packed(packed, images, ref_logits):
     """Bit-exact at 1 shard across ragged batch sizes, ONE compile total
     (5 imgs vs chunk 2: 3 chunks with a padded tail; 1 img: padded)."""
     fwd = make_sharded_forward(packed, data_shards=1, micro_batch=2,
-                               path="xla")
+                               plan=_plan(packed, "xla"))
     assert fwd.plan.chunk == 2
     np.testing.assert_array_equal(np.asarray(fwd(images)), ref_logits)
     np.testing.assert_array_equal(np.asarray(fwd(images[:1])), ref_logits[:1])
@@ -62,7 +69,7 @@ def test_parity_with_forward_packed(packed, images, ref_logits):
 
 def test_empty_batch(packed):
     fwd = make_sharded_forward(packed, data_shards=1, micro_batch=2,
-                               path="xla")
+                               plan=_plan(packed, "xla"))
     out = fwd(np.zeros((0, 32, 32, 3), np.float32))
     assert out.shape == (0, 10)
 
@@ -72,7 +79,7 @@ def test_two_d_plan_single_device(packed, images, ref_logits):
     stage columns cycle placement, results unchanged, still one compile
     per stage."""
     fwd = make_sharded_forward(packed, data_shards=1, micro_batch=2,
-                               n_stages=3, path="xla")
+                               n_stages=3, plan=_plan(packed, "xla"))
     assert fwd.plan.n_stages == 3
     assert fwd.plan.stage_plan.n_stages == 3
     np.testing.assert_array_equal(np.asarray(fwd(images)), ref_logits)
@@ -83,7 +90,7 @@ def test_plan_metadata_roundtrips():
     import json
     packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
     fwd = make_sharded_forward(packed, data_shards=1, micro_batch=4,
-                               n_stages=2, path="xla")
+                               n_stages=2, plan=_plan(packed, "xla"))
     meta = fwd.plan.describe()
     assert meta == json.loads(json.dumps(meta))       # JSON-clean
     assert meta["data_shards"] == 1 and meta["n_stages"] == 2
@@ -109,7 +116,7 @@ def test_rejects_bad_arguments(packed):
 
 def test_engine_routes_large_batches_to_sharded_forward(packed, images,
                                                         ref_logits):
-    eng = BCNNEngine.from_packed(packed, n_slots=2, path="xla",
+    eng = BCNNEngine.from_packed(packed, n_slots=2, plan=_plan(packed, "xla"),
                                  data_shards=1, data_micro_batch=2)
     assert eng.batch_forward is not None
     assert eng.batch_cache_size == 0                  # not yet used
@@ -121,7 +128,7 @@ def test_engine_routes_large_batches_to_sharded_forward(packed, images,
 
 def test_engine_routes_small_batches_through_slots(packed, images,
                                                    ref_logits):
-    eng = BCNNEngine.from_packed(packed, n_slots=2, path="xla",
+    eng = BCNNEngine.from_packed(packed, n_slots=2, plan=_plan(packed, "xla"),
                                  data_shards=1, data_micro_batch=2,
                                  batch_threshold=4)
     got = eng.classify_batch(images[:3])              # 3 < threshold 4
@@ -139,7 +146,7 @@ def test_engine_without_data_shards_still_classifies(packed, images,
                                                      ref_logits):
     """data_shards=0 (default): classify_batch falls back to the slot
     path for any size — behavior identical to submitting individually."""
-    eng = BCNNEngine.from_packed(packed, n_slots=2, path="xla")
+    eng = BCNNEngine.from_packed(packed, n_slots=2, plan=_plan(packed, "xla"))
     assert eng.batch_forward is None
     got = eng.classify_batch(images)
     np.testing.assert_array_equal(got, ref_logits)
@@ -147,7 +154,7 @@ def test_engine_without_data_shards_still_classifies(packed, images,
 
 
 def test_engine_classify_batch_rejects_bad_shape(packed):
-    eng = BCNNEngine.from_packed(packed, n_slots=2, path="xla")
+    eng = BCNNEngine.from_packed(packed, n_slots=2, plan=_plan(packed, "xla"))
     with pytest.raises(ValueError, match="batch shape"):
         eng.classify_batch(np.zeros((2, 16, 16, 3), np.float32))
 
@@ -155,26 +162,29 @@ def test_engine_classify_batch_rejects_bad_shape(packed):
 # ------------------------------------------------------------- multi-device
 
 _SUBPROC = textwrap.dedent("""
+    import dataclasses
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import bcnn
+    from repro.core.execution_plan import default_plan
     from repro.parallel.bcnn_data_parallel import make_sharded_forward
 
     assert len(jax.devices()) == 4, jax.devices()
     packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
+    plan = dataclasses.replace(default_plan(packed), path="xla")
     x = np.random.default_rng(0).random((6, 32, 32, 3)).astype(np.float32)
-    ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(x), path="xla"))
+    ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(x), plan=plan))
     for shards in (2, 4):
         fwd = make_sharded_forward(packed, data_shards=shards,
-                                   micro_batch=1, path="xla")
+                                   micro_batch=1, plan=plan)
         assert len(set(fwd.mesh.devices.flat)) == shards
         np.testing.assert_array_equal(np.asarray(fwd(x)), ref)   # ragged @4
         np.testing.assert_array_equal(np.asarray(fwd(x[:3])), ref[:3])
         assert fwd.cache_size() == 1, (shards, fwd.cache_size())
     # 2-D: 2 data shards x 2 pipeline stages over all 4 devices
     fwd = make_sharded_forward(packed, data_shards=2, micro_batch=2,
-                               n_stages=2, path="xla")
+                               n_stages=2, plan=plan)
     cols = {d for col in fwd._columns for d in col.devices}
     assert len(cols) == 4, cols
     np.testing.assert_array_equal(np.asarray(fwd(x)), ref)
@@ -183,7 +193,7 @@ _SUBPROC = textwrap.dedent("""
     # devices actually passed, not from the host total (construction only
     # -- placement logic, no compile)
     sub = make_sharded_forward(packed, devices=jax.devices()[:2],
-                               micro_batch=1, path="xla")
+                               micro_batch=1, plan=plan)
     assert sub.data_shards == 2, sub.plan
     assert set(sub.mesh.devices.flat) == set(jax.devices()[:2])
     print("BCNN_DATA_PARALLEL_OK")

@@ -9,15 +9,23 @@ The two hard ones, per the paper's online-serving scenario:
 
 Cheap scheduler-level behavior is tested through a toy forward; the packed
 9-layer BCNN itself backs the isolation test (module-scoped fold)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import bcnn
+from repro.core.execution_plan import default_plan
 from repro.serve import BCNNEngine, drive_poisson
 
 N_SLOTS = 4
+
+
+def _plan(packed, path):
+    """``default_plan(packed)`` on ``path``'s kernels."""
+    return dataclasses.replace(default_plan(packed), path=path)
 
 
 def toy_forward(x):
@@ -138,7 +146,7 @@ def test_classify_batch_empty_skips_device(packed):
     """Regression: an empty batch used to route through the bulk forward,
     paying a full padded-chunk device round-trip (and a compile) for zero
     images. It must early-return host-side on both kinds of engine."""
-    eng = BCNNEngine.from_packed(packed, n_slots=2, path="xla",
+    eng = BCNNEngine.from_packed(packed, n_slots=2, plan=_plan(packed, "xla"),
                                  data_shards=1, data_micro_batch=2)
     out = eng.classify_batch(np.zeros((0, 32, 32, 3), np.float32))
     assert out.shape == (0, 10) and out.dtype == np.float32
@@ -152,11 +160,13 @@ def test_classify_batch_empty_skips_device(packed):
 def test_cotenant_isolation_packed_bcnn(packed, images):
     """Paper BCNN, deployment path: logits for image 0 are bit-identical
     served alone vs sharing the step with 3 co-tenants."""
-    eng_alone = BCNNEngine.from_packed(packed, n_slots=N_SLOTS, path="xla")
+    eng_alone = BCNNEngine.from_packed(packed, n_slots=N_SLOTS,
+                                       plan=_plan(packed, "xla"))
     r = eng_alone.submit(images[0])
     alone = eng_alone.run()[r]
 
-    eng_shared = BCNNEngine.from_packed(packed, n_slots=N_SLOTS, path="xla")
+    eng_shared = BCNNEngine.from_packed(packed, n_slots=N_SLOTS,
+                                        plan=_plan(packed, "xla"))
     rids = [eng_shared.submit(im) for im in images]
     shared = eng_shared.run()
     np.testing.assert_array_equal(alone, shared[rids[0]])
@@ -164,11 +174,12 @@ def test_cotenant_isolation_packed_bcnn(packed, images):
 
 def test_packed_engine_matches_forward_packed(packed, images):
     """Engine logits ≡ a direct forward_packed call on the same batch."""
-    eng = BCNNEngine.from_packed(packed, n_slots=N_SLOTS, path="xla")
+    eng = BCNNEngine.from_packed(packed, n_slots=N_SLOTS,
+                                 plan=_plan(packed, "xla"))
     rids = [eng.submit(im) for im in images]
     out = eng.run()
     ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(images),
-                                         path="xla"))
+                                         plan=_plan(packed, "xla")))
     got = np.stack([out[r] for r in rids])
     np.testing.assert_array_equal(got, ref)
     assert eng.step_cache_size == 1

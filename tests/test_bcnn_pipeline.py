@@ -14,6 +14,7 @@ The hard invariants:
   different (simulated host) devices; subprocess-isolated like
   tests/test_pipeline.py so THIS process keeps seeing 1 device.
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -25,8 +26,14 @@ import numpy as np
 import pytest
 
 from repro.core import bcnn, bitpack
+from repro.core.execution_plan import default_plan
 from repro.parallel import bcnn_pipeline as bp
 from repro.serve import BCNNEngine
+
+
+def _plan(packed, path):
+    """``default_plan(packed)`` on ``path``'s kernels."""
+    return dataclasses.replace(default_plan(packed), path=path)
 
 
 @pytest.fixture(scope="module")
@@ -42,7 +49,7 @@ def images():
 @pytest.fixture(scope="module")
 def ref_logits(packed, images):
     return np.asarray(bcnn.forward_packed(packed, jnp.asarray(images),
-                                          path="xla"))
+                                          plan=_plan(packed, "xla")))
 
 
 # ---------------------------------------------------------------- stage plan
@@ -119,7 +126,7 @@ def test_boundary_roundtrip_exact():
 def test_parity_with_forward_packed(packed, images, ref_logits, n_stages):
     """Bit-exact across stage counts, with a ragged tail (5 imgs, mb=2)."""
     fwd = bp.make_pipelined_forward(packed, n_stages=n_stages,
-                                    micro_batch=2, path="xla")
+                                    micro_batch=2, plan=_plan(packed, "xla"))
     np.testing.assert_array_equal(np.asarray(fwd(images)), ref_logits)
     # ragged the other way: batch smaller than one micro-batch
     np.testing.assert_array_equal(np.asarray(fwd(images[:1])), ref_logits[:1])
@@ -132,7 +139,7 @@ def test_single_device_stage_cycling(packed, images, ref_logits):
     """More stages than devices: placement cycles, results unchanged."""
     dev = jax.devices()[0]
     fwd = bp.make_pipelined_forward(packed, n_stages=3, micro_batch=2,
-                                    devices=[dev], path="xla")
+                                    devices=[dev], plan=_plan(packed, "xla"))
     assert fwd.devices == (dev, dev, dev)
     np.testing.assert_array_equal(np.asarray(fwd(images)), ref_logits)
 
@@ -143,7 +150,7 @@ def test_engine_on_pipeline_zero_recompile(packed, images, ref_logits):
     """BCNNEngine riding the pipelined forward: occupancy sweep 1..n_slots
     keeps every per-stage jit cache at exactly 1, and logits match the
     single-device deployment path bit-for-bit."""
-    eng = BCNNEngine.from_packed(packed, n_slots=4, path="xla",
+    eng = BCNNEngine.from_packed(packed, n_slots=4, plan=_plan(packed, "xla"),
                                  pipeline_stages=2, pipeline_micro_batch=1)
     for k in range(1, 5):
         rids = [eng.submit(images[i % len(images)]) for i in range(k)]
@@ -157,18 +164,21 @@ def test_engine_on_pipeline_zero_recompile(packed, images, ref_logits):
 # ------------------------------------------------------------- multi-device
 
 _SUBPROC = textwrap.dedent("""
+    import dataclasses
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import bcnn
+    from repro.core.execution_plan import default_plan
     from repro.parallel import bcnn_pipeline as bp
 
     assert len(jax.devices()) == 2, jax.devices()
     packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
+    plan = dataclasses.replace(default_plan(packed), path="xla")
     x = np.random.default_rng(0).random((4, 32, 32, 3)).astype(np.float32)
-    ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(x), path="xla"))
+    ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(x), plan=plan))
     fwd = bp.make_pipelined_forward(packed, n_stages=2, micro_batch=1,
-                                    path="xla")
+                                    plan=plan)
     assert len(set(fwd.devices)) == 2, fwd.devices
     np.testing.assert_array_equal(np.asarray(fwd(x)), ref)
     assert fwd.cache_size() == 1

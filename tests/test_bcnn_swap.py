@@ -14,12 +14,15 @@ The contract under test:
 * shape/static-incompatible replacements and opaque forwards are
   rejected loudly.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import bcnn
+from repro.core.execution_plan import default_plan
 from repro.serve import BCNNEngine
 
 N_SLOTS = 3
@@ -29,6 +32,11 @@ VARIANTS = {
     "pipelined": {"pipeline_stages": 2, "pipeline_micro_batch": 1},
     "data-parallel": {"data_shards": 1, "data_micro_batch": 2},
 }
+
+
+def _plan(packed, path):
+    """``default_plan(packed)`` on ``path``'s kernels."""
+    return dataclasses.replace(default_plan(packed), path=path)
 
 
 @pytest.fixture(scope="module")
@@ -61,10 +69,11 @@ def _occupancy_sweep(eng, images):
 def test_swap_under_live_occupancy_sweep(variant, packed_a, packed_b,
                                          images):
     ref_a = np.asarray(bcnn.forward_packed(packed_a, jnp.asarray(images),
-                                           path="xla"))
+                                           plan=_plan(packed_a, "xla")))
     ref_b = np.asarray(bcnn.forward_packed(packed_b, jnp.asarray(images),
-                                           path="xla"))
-    eng = BCNNEngine.from_packed(packed_a, n_slots=N_SLOTS, path="xla",
+                                           plan=_plan(packed_b, "xla")))
+    eng = BCNNEngine.from_packed(packed_a, n_slots=N_SLOTS,
+                                 plan=_plan(packed_a, "xla"),
                                  **VARIANTS[variant])
     out = _occupancy_sweep(eng, images)
     np.testing.assert_array_equal(
@@ -89,8 +98,9 @@ def test_queued_requests_get_new_weights(packed_a, packed_b, images):
     with the post-swap net."""
     ref_b = np.asarray(bcnn.forward_packed(packed_b,
                                            jnp.asarray(images[:1]),
-                                           path="xla"))
-    eng = BCNNEngine.from_packed(packed_a, n_slots=N_SLOTS, path="xla")
+                                           plan=_plan(packed_b, "xla")))
+    eng = BCNNEngine.from_packed(packed_a, n_slots=N_SLOTS,
+                                 plan=_plan(packed_a, "xla"))
     rid = eng.submit(images[0])          # queued, not admitted (no step yet)
     drained = eng.swap_packed(packed_b)
     assert drained == {} and eng.sched.n_queued == 1
@@ -99,7 +109,8 @@ def test_queued_requests_get_new_weights(packed_a, packed_b, images):
 
 
 def test_incompatible_swap_rejected(packed_a, packed_b):
-    eng = BCNNEngine.from_packed(packed_a, n_slots=2, path="xla")
+    eng = BCNNEngine.from_packed(packed_a, n_slots=2,
+                                 plan=_plan(packed_a, "xla"))
     # a request pending across the FAILED swap attempts: rejection must
     # leave the engine fully untouched — nothing drained, nothing served
     rid = eng.submit(np.zeros((32, 32, 3), np.float32))
@@ -113,7 +124,8 @@ def test_incompatible_swap_rejected(packed_a, packed_b):
     assert eng.sched.n_queued == 1 and eng.steps_executed == 0
     # and it still serves with the old net
     ref_a = np.asarray(bcnn.forward_packed(
-        packed_a, jnp.zeros((1, 32, 32, 3), jnp.float32), path="xla"))
+        packed_a, jnp.zeros((1, 32, 32, 3), jnp.float32),
+        plan=_plan(packed_a, "xla")))
     np.testing.assert_array_equal(eng.run()[rid], ref_a[0])
 
 
@@ -127,15 +139,17 @@ def test_opaque_forward_rejects_swap(packed_b):
 def test_packed_forward_swap_direct(packed_a, packed_b):
     """The underlying PackedForward: swap updates ``.packed`` and reuses
     the compiled executable (cache stays 1 across swaps and calls)."""
-    fwd = bcnn.make_packed_forward(packed_a, path="xla")
+    fwd = bcnn.make_packed_forward(packed_a, plan=_plan(packed_a, "xla"))
     x = jnp.asarray(np.random.default_rng(2).random(
         (2, 32, 32, 3)).astype(np.float32))
     np.testing.assert_array_equal(
         np.asarray(fwd(x)),
-        np.asarray(bcnn.forward_packed(packed_a, x, path="xla")))
+        np.asarray(bcnn.forward_packed(packed_a, x,
+                                       plan=_plan(packed_a, "xla"))))
     fwd.swap(packed_b)
     assert fwd.packed is packed_b
     np.testing.assert_array_equal(
         np.asarray(fwd(x)),
-        np.asarray(bcnn.forward_packed(packed_b, x, path="xla")))
+        np.asarray(bcnn.forward_packed(packed_b, x,
+                                       plan=_plan(packed_b, "xla"))))
     assert fwd.cache_size() == 1

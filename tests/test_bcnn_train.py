@@ -12,12 +12,15 @@ trained-artifact lifecycle:
   → the serving engine loads the artifact and its slot/batch results
   match the training-graph oracle's top-1 decisions.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import bcnn, bcnn_artifact
+from repro.core.execution_plan import default_plan
 from repro.serve import BCNNEngine
 from repro.train import bcnn_train
 from repro.train import checkpoint as ckpt_lib
@@ -109,7 +112,9 @@ def test_lifecycle_end_to_end(tmp_path, resumed_run):
     oracle = np.argmax(np.asarray(
         bcnn.forward_eval(state.params, jnp.asarray(x))), -1)
 
-    eng = BCNNEngine.from_packed(loaded, n_slots=2, path="xla")
+    eng = BCNNEngine.from_packed(
+        loaded, n_slots=2,
+        plan=dataclasses.replace(default_plan(loaded), path="xla"))
     rids = [eng.submit(img) for img in x]
     out = eng.run()
     slot_top1 = np.argmax(np.stack([out[r] for r in rids]), -1)

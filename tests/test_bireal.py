@@ -3,6 +3,7 @@ CPU: the packed forward against the plain float32 reference, the
 zero-padding border correction against a zero-padded conv, the engine's
 slot and bulk paths with uint8 pixels, hot-swap, and the Table 2 forward
 left as it was by the packed-model seam."""
+import dataclasses
 import hashlib
 
 import jax
@@ -51,7 +52,8 @@ def test_packed_forward_matches_reference(model, path):
     stream is exact in float32 in both, so the logits agree to the bit
     (the head runs the same float32 ops on the same features)."""
     params, packed = model
-    plan = execution_plan.build_plan(packed, path=path)
+    plan = dataclasses.replace(execution_plan.default_plan(packed),
+                               path=path)
     x = pixels(3)
     got = np.asarray(jax.jit(
         lambda x: bireal.forward_packed(packed, x, plan))(x))
@@ -86,7 +88,8 @@ def test_border_correction_is_zero_padding(h, w, stride, path):
 def engine(model):
     """One engine for the tests below, which leave it serving ``model``:
     its slot step and bulk forward compile once."""
-    plan = execution_plan.build_plan(model[1], path="mxu")
+    plan = dataclasses.replace(execution_plan.default_plan(model[1]),
+                               path="mxu")
     return BCNNEngine.from_packed(model[1], plan=plan, n_slots=4,
                                   data_shards=1, data_micro_batch=4)
 

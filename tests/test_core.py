@@ -1,5 +1,7 @@
 """Correctness of the paper's §3 reformulation: the packed bit path must agree
 with the real-valued ±1 path bit-for-bit (eqs. 5/6/8 equivalences)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +9,7 @@ import pytest
 
 from repro.core import bconv, bcnn, bitpack, blinear
 from repro.core.binarize import binarize_ste, clip_latent
+from repro.core.execution_plan import default_plan
 from repro.core.normbinarize import (BNParams, batchnorm_inference,
                                      fold_threshold, norm_binarize)
 
@@ -96,7 +99,8 @@ def test_bcnn_eval_vs_packed():
     x = jax.random.uniform(jax.random.PRNGKey(8), (2, 32, 32, 3))
     logits_eval = bcnn.forward_eval(params, x)
     packed = bcnn.fold_model(params)
-    logits_packed = bcnn.forward_packed(packed, x)
+    logits_packed = bcnn.forward_packed(
+        packed, x, plan=dataclasses.replace(default_plan(packed), path="mxu"))
     assert logits_eval.shape == (2, 10) and logits_packed.shape == (2, 10)
     assert not np.any(np.isnan(np.asarray(logits_packed)))
     np.testing.assert_allclose(np.asarray(logits_eval),

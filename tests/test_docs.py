@@ -17,8 +17,7 @@ def _load_checker():
 
 def test_required_docs_exist():
     for f in ("README.md", "docs/ARCHITECTURE.md", "docs/SERVING.md",
-              "docs/PIPELINE.md", "benchmarks/README.md",
-              "src/repro/kernels/README.md"):
+              "docs/PIPELINE.md", "src/repro/kernels/README.md"):
         assert (ROOT / f).exists(), f"missing required doc: {f}"
 
 

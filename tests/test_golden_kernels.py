@@ -5,6 +5,8 @@ Unlike the oracle-parity tests (test_kernels.py), these pin the kernels to
 hand-computable fixtures — so a refactor that breaks both a kernel and its
 oracle the same way is still caught, without needing a TPU.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -127,9 +129,15 @@ def _golden_input_tile():
 @pytest.mark.parametrize("conv_strategy", ["direct", "im2col"])
 def test_forward_packed_golden_logits(conv_strategy):
     from repro.core import bcnn
+    from repro.core.execution_plan import default_plan
     packed = _golden_packed()
+    base = default_plan(packed)
+    plan = dataclasses.replace(
+        base, path="xla",
+        conv_strategy=tuple(None if s is None else conv_strategy
+                            for s in base.conv_strategy))
     logits = bcnn.forward_packed(packed, jnp.asarray(_golden_input_tile()),
-                                 path="xla", conv_strategy=conv_strategy)
+                                 plan=plan)
     got = np.asarray(logits)
     want = np.asarray(LOGITS_GOLD, np.float32)
     np.testing.assert_array_equal(np.argmax(got, -1), np.argmax(want, -1))
@@ -142,17 +150,20 @@ def test_forward_golden_logits_fused(deployment):
     bit-exact, so the checked-in tile needs no fused variant — on all three
     deployment forwards (single-device, stage-pipelined, data-parallel)."""
     from repro.core import bcnn
+    from repro.core.execution_plan import default_plan
     packed = _golden_packed()
+    plan = dataclasses.replace(default_plan(packed), path="xla",
+                               conv_fusion=True)
     if deployment == "single":
-        fwd = bcnn.make_packed_forward(packed, path="xla", conv_fusion=True)
+        fwd = bcnn.make_packed_forward(packed, plan=plan)
     elif deployment == "pipelined":
         from repro.parallel.bcnn_pipeline import make_pipelined_forward
         fwd = make_pipelined_forward(packed, n_stages=2, micro_batch=1,
-                                     path="xla", conv_fusion=True)
+                                     plan=plan)
     else:
         from repro.parallel.bcnn_data_parallel import make_sharded_forward
         fwd = make_sharded_forward(packed, data_shards=1, micro_batch=2,
-                                   path="xla", conv_fusion=True)
+                                   plan=plan)
     got = np.asarray(fwd(jnp.asarray(_golden_input_tile())))
     want = np.asarray(LOGITS_GOLD, np.float32)
     np.testing.assert_array_equal(np.argmax(got, -1), np.argmax(want, -1))

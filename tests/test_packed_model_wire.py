@@ -10,6 +10,8 @@ serve/bcnn_engine.py::BCNNEngine.classify_batch).
 * host images, device images and the wire form all hit the one compiled
   chunk unit, and ``input_counts`` says which route each call took.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,6 +26,11 @@ from repro.serve import BCNNEngine
 WIRE = {"f32-32x32x3": ((32, 32, 3), np.float32, (3072, np.uint32)),
         "u8-224x224x3": ((224, 224, 3), np.uint8, (37632, np.uint32)),
         "u8-3x3x3": ((3, 3, 3), np.uint8, (27, np.uint8))}
+
+
+def _plan(packed, path):
+    """``default_plan(packed)`` on ``path``'s kernels."""
+    return dataclasses.replace(execution_plan.default_plan(packed), path=path)
 
 
 def batch(n, shape, dtype, seed=0):
@@ -92,7 +99,7 @@ def model(request):
         packed = bireal.fold(bireal.init(3, geom), geom)
         x = batch(6, packed.input_shape, np.uint8, seed=1)
         chunk = 4
-    return packed, execution_plan.build_plan(packed, path="xla"), x, chunk
+    return packed, _plan(packed, "xla"), x, chunk
 
 
 @pytest.fixture(scope="module")
@@ -133,7 +140,7 @@ def test_every_input_route_hits_one_compile(model, want):
 
 def test_input_counts_without_a_bulk_path():
     packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
-    eng = BCNNEngine.from_packed(packed, n_slots=2, path="xla")
+    eng = BCNNEngine.from_packed(packed, n_slots=2, plan=_plan(packed, "xla"))
     assert eng.batch_input_counts == {}
 
 
@@ -143,9 +150,9 @@ def test_stage_columns_take_the_wire_form():
     packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
     x = batch(3, packed.input_shape, np.float32)
     want = np.asarray(bcnn.forward_packed(packed, jnp.asarray(x),
-                                          path="xla"))
+                                          plan=_plan(packed, "xla")))
     fwd = make_sharded_forward(packed, data_shards=1, micro_batch=2,
-                               n_stages=2, path="xla")
+                               n_stages=2, plan=_plan(packed, "xla"))
     np.testing.assert_array_equal(np.asarray(fwd(x)), want)
     np.testing.assert_array_equal(
         np.asarray(fwd(jnp.asarray(packed_model.to_wire(x)))), want)

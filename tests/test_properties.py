@@ -8,10 +8,12 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis "
                     "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
+import dataclasses
 import functools
 import types
 
 from repro.core import bcnn, bconv, blinear, bitpack
+from repro.core.execution_plan import default_plan
 from repro.core.normbinarize import BNParams, fold_threshold, norm_binarize
 from repro.core.throughput import balance_stages, pipeline_throughput
 from repro.serve import (AutoscaleConfig, BCNNEngine, FleetAutoscaler,
@@ -90,10 +92,11 @@ def test_apply_packed_layer_matches_eval_layerwise(model_seed, input_seed,
     params, packed = _bcnn_model(model_seed)
     x = jnp.asarray(np.random.default_rng(input_seed)
                     .random((batch, 32, 32, 3)).astype(np.float32))
+    plan = dataclasses.replace(default_plan(packed), path="xla")
     h = x
     a = bconv.fpconv_apply(params.conv1, x)                     # oracle, ±1
     for idx in range(bcnn.N_LAYERS):
-        h = bcnn.apply_packed_layer(packed, idx, h, path="xla")
+        h = bcnn.apply_packed_layer(packed, idx, h, plan)
         if idx >= 1 and idx <= 5:
             a = bconv.apply_train(params.convs[idx - 1], a,
                                   maxpool=bcnn.CONV_SPECS[idx][2])
@@ -127,8 +130,10 @@ def test_conv_fusion_parity(model_seed, input_seed, batch):
     _, packed = _bcnn_model(model_seed)
     x = jnp.asarray(np.random.default_rng(input_seed)
                     .random((batch, 32, 32, 3)).astype(np.float32))
-    ref = bcnn.forward_packed(packed, x, path="xla", conv_fusion=False)
-    got = bcnn.forward_packed(packed, x, path="xla", conv_fusion=True)
+    plan = dataclasses.replace(default_plan(packed), path="xla")
+    ref = bcnn.forward_packed(packed, x, plan=plan)
+    got = bcnn.forward_packed(
+        packed, x, plan=dataclasses.replace(plan, conv_fusion=True))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 

@@ -10,6 +10,7 @@ completes (zero drops), logits are bit-exact for the weight epoch that
 served them, and ``step_cache_size == 1`` on every replica. A small
 threaded smoke exercises the real worker-thread machinery end to end.
 """
+import dataclasses
 import time
 
 import jax
@@ -18,9 +19,15 @@ import numpy as np
 import pytest
 
 from repro.core import bcnn
+from repro.core.execution_plan import default_plan
 from repro.serve import (BCNNEngine, RequestClass, Router, RouterOverload,
                          RouterShutdown, drive_mixed_poisson)
 from repro.serve.router import BULK, ONLINE
+
+
+def _plan(packed, path):
+    """``default_plan(packed)`` on ``path``'s kernels."""
+    return dataclasses.replace(default_plan(packed), path=path)
 
 
 class StepClock:
@@ -193,14 +200,15 @@ def test_rolling_swap_mixed_poisson_zero_drops_bit_exact(packed_a,
     epoch that served them, and ``step_cache_size == 1`` per replica."""
     clock = StepClock(dt=2e-3)
     router = Router.from_packed(packed_a, n_replicas=2, n_slots=2,
-                                path="xla", threaded=False, clock=clock)
+                                plan=_plan(packed_a, "xla"), threaded=False,
+                                clock=clock)
     n = 20
     images = np.random.default_rng(0).random((n, 32, 32, 3)).astype(
         np.float32)
     ref_a = np.asarray(bcnn.forward_packed(packed_a, jnp.asarray(images),
-                                           path="xla"))
+                                           plan=_plan(packed_a, "xla")))
     ref_b = np.asarray(bcnn.forward_packed(packed_b, jnp.asarray(images),
-                                           path="xla"))
+                                           plan=_plan(packed_b, "xla")))
     d = drive_mixed_poisson(router, images, rate_hz=100.0,
                             mix={"online": 3.0, "bulk": 1.0}, seed=1,
                             swap_to=packed_b, swap_at_frac=0.5)
@@ -225,11 +233,12 @@ def test_rolling_swap_mixed_poisson_zero_drops_bit_exact(packed_a,
 def test_rolling_swap_incompatible_leaves_fleet_serving(packed_a, packed_b):
     clock = StepClock()
     router = Router.from_packed(packed_a, n_replicas=2, n_slots=2,
-                                path="xla", threaded=False, clock=clock)
+                                plan=_plan(packed_a, "xla"), threaded=False,
+                                clock=clock)
     images = np.random.default_rng(2).random((4, 32, 32, 3)).astype(
         np.float32)
     ref_a = np.asarray(bcnn.forward_packed(packed_a, jnp.asarray(images),
-                                           path="xla"))
+                                           plan=_plan(packed_a, "xla")))
     reqs = [router.submit(im) for im in images]
     bad = packed_b._replace(fc3_k=packed_b.fc3_k + 1)
     with pytest.raises(ValueError, match="static"):
@@ -245,13 +254,13 @@ def test_rolling_swap_incompatible_leaves_fleet_serving(packed_a, packed_b):
 
 def test_rolling_swap_while_idle(packed_a, packed_b):
     router = Router.from_packed(packed_a, n_replicas=2, n_slots=2,
-                                path="xla", threaded=False,
+                                plan=_plan(packed_a, "xla"), threaded=False,
                                 clock=StepClock())
     assert router.rolling_swap(packed_b) == 2
     assert all(rep.epoch == 1 for rep in router.replicas)
     x = np.random.default_rng(3).random((2, 32, 32, 3)).astype(np.float32)
     ref_b = np.asarray(bcnn.forward_packed(packed_b, jnp.asarray(x),
-                                           path="xla"))
+                                           plan=_plan(packed_b, "xla")))
     np.testing.assert_array_equal(router.classify_batch(x), ref_b)
     assert all(rep.step_cache_size == 1 for rep in router.replicas)
 
@@ -307,7 +316,7 @@ def test_threaded_router_end_to_end(packed_a, packed_b):
     """Real worker threads: mixed Poisson wall-clock drive with a
     concurrent rolling swap; zero drops, zero recompiles."""
     router = Router.from_packed(packed_a, n_replicas=2, n_slots=2,
-                                path="xla", threaded=True)
+                                plan=_plan(packed_a, "xla"), threaded=True)
     try:
         images = np.random.default_rng(4).random((12, 32, 32, 3)).astype(
             np.float32)

@@ -85,7 +85,7 @@ def test_latency_stats_zero_span_is_finite_and_json():
     """Regression: a zero wall span (e.g. a single completed request under
     a coarse clock) must yield a well-defined, JSON-valid throughput —
     not ``float("inf")``, which ``json.dump`` emits as bare ``Infinity``
-    and breaks downstream parsers of the fig7 CI artifact."""
+    and breaks downstream JSON parsers."""
     import json
     import math
     req = Request(rid=0, payload=None, done=True,

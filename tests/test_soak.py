@@ -23,6 +23,7 @@ What a full run must hold FLAT or CLOSED, every iteration:
   that are bit-exact for its stamped weight epoch or a typed
   ``RouterOverload``-family error. None vanish.
 """
+import dataclasses
 import time
 
 import jax
@@ -31,6 +32,7 @@ import numpy as np
 import pytest
 
 from repro.core import bcnn
+from repro.core.execution_plan import default_plan
 from repro.serve import AutoscaleConfig, Router, RouterOverload
 
 psutil = pytest.importorskip(
@@ -64,14 +66,15 @@ def test_soak_elastic_fleet_flat_caches_bounded_rss_closed_ledger():
                           cooldown_s=0.5, interval_s=0.001)
     packed = [bcnn.fold_model(bcnn.init(jax.random.PRNGKey(k)))
               for k in (0, 1)]
+    plan = dataclasses.replace(default_plan(packed[0]), path="xla")
     router = Router.from_packed(packed[0], n_replicas=2, n_slots=2,
-                                path="xla", threaded=False, clock=clock,
+                                plan=plan, threaded=False, clock=clock,
                                 autoscale=cfg, max_queue=256,
                                 online_reserve=1, bulk_chunk=2)
     rng = np.random.default_rng(11)
     pool = rng.random((POOL, 32, 32, 3)).astype(np.float32)
     # per-weight-set reference logits: epoch e serves packed[e % 2]
-    refs = [np.asarray(bcnn.forward_packed(p, jnp.asarray(pool), path="xla"))
+    refs = [np.asarray(bcnn.forward_packed(p, jnp.asarray(pool), plan=plan))
             for p in packed]
     base_batch_cache = [(r.id, r.engine.batch_cache_size)
                         for r in router.replicas]
@@ -140,8 +143,9 @@ def test_fault_injection_replica_death_respawn_closed_ledger():
     logits bit-exact to the packed reference. No request is silently lost."""
     clock = StepClock(dt=1e-3)
     packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
+    plan = dataclasses.replace(default_plan(packed), path="xla")
     router = Router.from_packed(
-        packed, n_replicas=2, n_slots=2, path="xla", threaded=False,
+        packed, n_replicas=2, n_slots=2, plan=plan, threaded=False,
         clock=clock,
         # huge cooldown: only the min_replicas floor can explain a respawn
         autoscale=AutoscaleConfig(min_replicas=2, max_replicas=3,
@@ -151,7 +155,7 @@ def test_fault_injection_replica_death_respawn_closed_ledger():
     rng = np.random.default_rng(3)
     pool = rng.random((8, 32, 32, 3)).astype(np.float32)
     ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(pool),
-                                         path="xla"))
+                                         plan=plan))
 
     reqs = [router.submit(im) for im in pool[:4]]
     router.pump()                       # first wave served, slots warm
@@ -195,8 +199,9 @@ def test_fault_injection_threaded_replica_death():
     exits, the router requeues its orphans, the controller thread respawns
     capacity, and every submitted request completes."""
     packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
+    plan = dataclasses.replace(default_plan(packed), path="xla")
     router = Router.from_packed(
-        packed, n_replicas=2, n_slots=2, path="xla", threaded=True,
+        packed, n_replicas=2, n_slots=2, plan=plan, threaded=True,
         autoscale=AutoscaleConfig(min_replicas=2, max_replicas=3,
                                   up_watermark=50.0, down_watermark=1.0,
                                   window_s=0.02, cooldown_s=1e9,
@@ -205,7 +210,7 @@ def test_fault_injection_threaded_replica_death():
         rng = np.random.default_rng(4)
         pool = rng.random((6, 32, 32, 3)).astype(np.float32)
         ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(pool),
-                                             path="xla"))
+                                             plan=plan))
         first = [router.submit(im) for im in pool]
         for r in first:
             r.wait(timeout=60.0)

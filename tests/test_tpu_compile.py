@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import bcnn, bireal, execution_plan
+from repro.core import bcnn, bireal, execution_plan, packed_model
 from repro.kernels import ops
 
 BATCH = 4               # the serving engine's slot count (SERVE_N_SLOTS)
@@ -117,7 +117,6 @@ BIREAL18 = bireal.geometry(
 def test_bireal_forward_compiles(one_chip, monkeypatch):
     """The whole Bi-Real Net-18 forward at its published widths, 8 images
     of 224x224 uint8 pixels, on the default plan (Pallas ``mxu``)."""
-    from repro.core import packed_model
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     packed = bireal.fold(bireal.init(0, BIREAL18), BIREAL18)
     plan = execution_plan.default_plan(packed, backend="tpu")
@@ -219,7 +218,7 @@ def test_default_plan_forward_compiles(one_chip, monkeypatch):
     packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
     plan = execution_plan.default_plan(packed, backend="tpu")
     assert plan.path == "mxu"
-    arrays, rebuild = bcnn.split_packed(packed)
+    arrays, rebuild = packed_model.split(packed)
 
     def step(arrs, x01):
         return bcnn.forward_packed(rebuild(arrs), x01, plan=plan)
@@ -237,7 +236,7 @@ def test_layer_scopes_leave_the_kernel_names(one_chip, monkeypatch):
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     packed = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(0)))
     plan = execution_plan.default_plan(packed, backend="tpu")
-    arrays, rebuild = bcnn.split_packed(packed)
+    arrays, rebuild = packed_model.split(packed)
 
     def step(arrs, x01):
         return bcnn.forward_packed(rebuild(arrs), x01, plan=plan)

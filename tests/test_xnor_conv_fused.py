@@ -9,12 +9,15 @@ CPU) — while the planner partitions layers without ever fusing across a
 max-pool resolution drop or a pipeline stage cut, and the fused forward
 keeps the one-compile / zero-recompile-hot-swap contracts.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import bcnn, bconv
+from repro.core.execution_plan import default_plan
 from repro.kernels import ops
 
 # the two fusible same-resolution pairs (CONV-3/4 at 16x16, CONV-5/6 at 8x8)
@@ -30,6 +33,12 @@ def packed():
     return bcnn.fold_model(bcnn.init(jax.random.PRNGKey(3)))
 
 
+def _plan(packed, path, **fields):
+    """``default_plan(packed)`` on ``path``'s kernels, other fields
+    replaced as given."""
+    return dataclasses.replace(default_plan(packed), path=path, **fields)
+
+
 def _bits(seed: int, n: int, first: int) -> jnp.ndarray:
     h, w, c = PAIR_INPUT[first]
     u = jax.random.uniform(jax.random.PRNGKey(seed), (n, h, w, c))
@@ -37,22 +46,24 @@ def _bits(seed: int, n: int, first: int) -> jnp.ndarray:
 
 
 def _sequential(packed, pair, a, *, strategy) -> np.ndarray:
+    strategies = tuple(None if s is None else strategy
+                       for s in default_plan(packed).conv_strategy)
+    plan = _plan(packed, "xla", conv_strategy=strategies)
     h = a
     for idx in pair:
-        h = bcnn.apply_packed_layer(packed, idx, h, path="xla",
-                                    conv_strategy=strategy)
+        h = bcnn.apply_packed_layer(packed, idx, h, plan)
     return np.asarray(h)
 
 
 # ------------------------------------------------------------- the planner
 
-def test_plan_layer_groups_exact():
+def test_plan_layer_groups_exact(packed):
     assert bcnn.plan_layer_groups(conv_fusion=True) == \
         ((0,), (1,), (2, 3), (4, 5), (6,), (7,), (8,))
     assert bcnn.plan_layer_groups(conv_fusion=False) == SINGLETONS
-    # None defers to the module default (opt-in: off)
-    assert bconv.DEFAULT_CONV_FUSION is False
-    assert bcnn.plan_layer_groups() == SINGLETONS
+    # the default plan leaves fusion off (opt-in)
+    fusion = default_plan(packed).conv_fusion
+    assert bcnn.plan_layer_groups(conv_fusion=fusion) == SINGLETONS
 
 
 def test_plan_layer_groups_respects_stage_cuts():
@@ -90,7 +101,7 @@ def test_apply_packed_group_rejects_bad_pairs(packed):
     a = _bits(0, 1, 2)
     for bad in [(2, 4), (0, 1), (5, 6), (3, 2)]:
         with pytest.raises(ValueError, match="fusible"):
-            bcnn.apply_packed_group(packed, bad, a, path="xla")
+            bcnn.apply_packed_group(packed, bad, a, _plan(packed, "xla"))
 
 
 # ----------------------------------------------------------- pair parity
@@ -103,7 +114,7 @@ def test_fused_pair_parity_xla(packed, pair, strategy, n):
     sequential conv strategy and ragged batch sizes."""
     a = _bits(10 * pair[0] + n, n, pair[0])
     ref = _sequential(packed, pair, a, strategy=strategy)
-    got = bcnn.apply_packed_group(packed, pair, a, path="xla")
+    got = bcnn.apply_packed_group(packed, pair, a, _plan(packed, "xla"))
     np.testing.assert_array_equal(np.asarray(got), ref)
 
 
@@ -115,7 +126,7 @@ def test_fused_pair_parity_pallas_interpret(packed, pair, path):
     mode on CPU, against the sequential fold."""
     a = _bits(pair[0], 1, pair[0])
     ref = _sequential(packed, pair, a, strategy="direct")
-    got = bcnn.apply_packed_group(packed, pair, a, path=path)
+    got = bcnn.apply_packed_group(packed, pair, a, _plan(packed, path))
     np.testing.assert_array_equal(np.asarray(got), ref)
 
 
@@ -143,19 +154,20 @@ def test_pair_kernel_compiles_once(packed):
 
 
 def test_fused_forward_compile_once_and_hot_swap(packed):
-    """make_packed_forward(conv_fusion=True): parity with the unfused
+    """make_packed_forward on a fused plan: parity with the unfused
     forward, exactly one compile across repeat calls AND a weight
-    hot-swap (the `split_packed` statics are unchanged by fusion)."""
+    hot-swap (the `packed_model.split` statics are unchanged by fusion)."""
     x = np.random.default_rng(0).random((2, 32, 32, 3)).astype(np.float32)
-    ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(x), path="xla"))
-    fwd = bcnn.make_packed_forward(packed, path="xla", conv_fusion=True)
+    xla = _plan(packed, "xla")
+    ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(x), plan=xla))
+    fwd = bcnn.make_packed_forward(
+        packed, plan=dataclasses.replace(xla, conv_fusion=True))
     np.testing.assert_array_equal(np.asarray(fwd(x)), ref)
     np.testing.assert_array_equal(np.asarray(fwd(x)), ref)
     assert fwd.cache_size() == 1
     packed2 = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(4)))
     fwd.swap(packed2)
-    ref2 = np.asarray(bcnn.forward_packed(packed2, jnp.asarray(x),
-                                          path="xla"))
+    ref2 = np.asarray(bcnn.forward_packed(packed2, jnp.asarray(x), plan=xla))
     np.testing.assert_array_equal(np.asarray(fwd(x)), ref2)
     assert fwd.cache_size() == 1
 
@@ -167,10 +179,11 @@ def test_engine_fused_zero_recompile_across_swap(packed):
     from repro.serve import BCNNEngine
     rng = np.random.default_rng(2)
     imgs = rng.random((3, 32, 32, 3)).astype(np.float32)
+    xla = _plan(packed, "xla")
     ref = np.asarray(bcnn.forward_packed(packed, jnp.asarray(imgs),
-                                         path="xla"))
-    eng = BCNNEngine.from_packed(packed, n_slots=2, path="xla",
-                                 conv_fusion=True)
+                                         plan=xla))
+    eng = BCNNEngine.from_packed(
+        packed, n_slots=2, plan=dataclasses.replace(xla, conv_fusion=True))
     rids = [eng.submit(img) for img in imgs]
     out = eng.run()
     for rid, want in zip(rids, ref):
@@ -178,7 +191,7 @@ def test_engine_fused_zero_recompile_across_swap(packed):
     packed2 = bcnn.fold_model(bcnn.init(jax.random.PRNGKey(4)))
     eng.swap_packed(packed2)
     ref2 = np.asarray(bcnn.forward_packed(packed2, jnp.asarray(imgs[:1]),
-                                          path="xla"))
+                                          plan=xla))
     rid = eng.submit(imgs[0])
     np.testing.assert_array_equal(eng.run()[rid], ref2[0])
     assert eng.step_cache_size == 1

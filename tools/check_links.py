@@ -39,7 +39,6 @@ DEFAULT_FILES = [
     "docs/SERVING.md",
     "docs/PIPELINE.md",
     "docs/TRAINING.md",
-    "benchmarks/README.md",
     "src/repro/kernels/README.md",
     "src/repro/serve/slots.py",
     "src/repro/serve/engine.py",
@@ -56,7 +55,6 @@ DEFAULT_FILES = [
     "src/repro/train/bcnn_train.py",
     "src/repro/core/bcnn_artifact.py",
     "src/repro/launch/train_bcnn.py",
-    "benchmarks/fig7.py",
     "src/repro/models/xnor_lm.py",
     "src/repro/core/blinear.py",
     "src/repro/configs/xnor_lm_tiny.py",
